@@ -11,7 +11,7 @@ from __future__ import annotations
 
 from benchmarks.common import Table
 from repro.core.scan.policy import choose
-from repro.launch.mesh import HBM_BW
+from repro.launch.mesh import TARGET_KIND, peaks
 
 
 def run() -> Table:
@@ -38,7 +38,7 @@ def run_traffic_model() -> Table:
         ("Kernel(-P)", 8, "same, explicit VMEM tiles"),
     ]
     for name, bpe, note in rows:
-        t.add(name, bpe, bpe * 1e9 / HBM_BW, note)
+        t.add(name, bpe, bpe * 1e9 / peaks(TARGET_KIND).hbm_bw, note)
     return t
 
 

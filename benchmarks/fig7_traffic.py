@@ -50,16 +50,19 @@ def run() -> Table:
 
 
 def run_device_parallel() -> Table:
-    """The m-device two-pass scan's collective footprint (subprocess with
-    8 host devices; prints the `sums`-exchange bytes per schedule)."""
+    """The m-device two-pass scan's collective footprint (a CPU
+    subprocess with 8 host devices — this parent may hold the chip, so
+    the child never reaches for it; prints the `sums`-exchange bytes per
+    schedule, counted from the compiled HLO)."""
     prog = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import scan as scanlib
+from repro.dist.sharding import make_mesh
 from repro.roofline.analyze import collective_bytes_from_hlo
-mesh = jax.make_mesh((8,), ("d",))
+mesh = make_mesh((8,), ("d",))
 N = 1 << 22
 x = jax.ShapeDtypeStruct((N,), jnp.float32)
 sh = NamedSharding(mesh, P("d"))
@@ -71,7 +74,7 @@ for ex in ("all_gather", "hillis_permute", "ring"):
     coll = collective_bytes_from_hlo(comp.as_text())
     print(f"{ex}\t{sum(coll.values())}\t{coll}")
 """
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
     res = subprocess.run([sys.executable, "-c", prog], capture_output=True,

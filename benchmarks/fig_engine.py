@@ -1,14 +1,15 @@
 """Scan-engine sweep: schedule × monoid, one table.
 
 The engine's promise is that each grid organization is written once and
-runs over every registered monoid. This sweep drives all sixteen
-(schedule, monoid) cells through the family ``ops`` wrappers, checks the
-cross-schedule parity invariant on the fly — BIT-parity for the
-carry/decoupled/fused trio (shared in-tile network), tolerance for the
+runs over every registered monoid. This sweep drives the twelve
+(schedule, monoid) cells that run in interpret mode through the family
+``ops`` wrappers (the fused schedule runs only compiled on a TPU),
+checks the cross-schedule parity invariant on the fly — BIT-parity for
+carry/decoupled (shared in-tile network), tolerance for the
 tree's different association on float data (``atol<=2e-4``; integral
 monoids stay bitwise) — and reports wall-clock plus what
-``policy.choose_schedule`` would pick for the shape, so the four-way
-policy rule can be eyeballed against measurement on real hardware (on
+``policy.choose_schedule`` would pick for the shape, so the policy
+rule can be eyeballed against measurement on real hardware (on
 the CPU container the kernels run in interpret mode and wall-clock
 mostly reflects algorithmic structure).
 """
@@ -27,7 +28,7 @@ from repro.kernels.scan_blocked import ops as sb_ops
 from repro.kernels.segscan import ops as seg_ops
 from repro.kernels.ssm_scan import ops as ssm_ops
 
-SCHEDULES = ("carry", "decoupled", "fused", "tree")
+SCHEDULES = ("carry", "decoupled", "tree")
 TREE_ATOL = 2e-4
 
 

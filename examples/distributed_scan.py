@@ -18,11 +18,12 @@ import numpy as np                                                # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P        # noqa: E402
 
 from repro.core import scan as scanlib                            # noqa: E402
+from repro.dist.sharding import make_mesh                         # noqa: E402
 from repro.roofline.analyze import collective_bytes_from_hlo      # noqa: E402
 
 
 def main():
-    mesh = jax.make_mesh((8,), ("d",))
+    mesh = make_mesh((8,), ("d",))
     n = 1 << 20
     x = jnp.asarray(np.random.default_rng(0).standard_normal(n), jnp.float32)
     sh = NamedSharding(mesh, P("d"))

@@ -35,11 +35,10 @@ by the monoid-generic engine in ``repro.kernels.scan_engine``:
                (read 2n + write n; the paper's SIMD2-P, Observation 3).
   'fused'      the same reduce-then-scan organization in ONE launch: each
                chunk scans once and chains its prefix to its successor
-               through cross-chunk semaphores — decoupled's parallelism
-               at the carry chain's traffic (read n + write n). Where the
-               native single-launch path cannot run (interpret mode, no
-               semaphore API) the engine degrades to the two-launch
-               decoupled schedule, bit-identically.
+               through cross-chunk semaphores. Never picked here: it
+               needs one semaphore per grid cell, and the chip's
+               semaphore memory holds a few hundred, far short of the
+               chunk counts of the long rows it was meant for.
   'tree'       carry's grid with the work-efficient Blelloch sweep as the
                in-tile network (the paper's §3.3 balanced tree): O(b)
                combines per b-element tile instead of the log network's
@@ -52,13 +51,11 @@ by the monoid-generic engine in ``repro.kernels.scan_engine``:
   machine; cheapest traffic) — upgraded to the tree network when the tile
   is long (``block_elems >= TREE_BLOCK_ELEMS``), where the in-tile
   combine count dominates and work-efficiency pays; a parallel-sequence
-  schedule when a long row would otherwise serialize — ``batch < cores``
-  AND the row spans multiple blocks AND there are at least
-  ``cores // batch`` chunks to spread. Of the two parallel
-  organizations, fused is preferred (it erases decoupled's second read);
-  ``prefer_fused=False`` forces the two-launch form. Serve-engine decode
-  and SSM prefill (B=1, N ≥ 2^22) land on fused/decoupled; training
-  shapes (B ≥ 8) keep the carry chain at default blocks.
+  schedule (decoupled) when a long row would otherwise serialize —
+  ``batch < cores`` AND the row spans multiple blocks AND there are at
+  least ``cores // batch`` chunks to spread. Serve-engine decode and SSM
+  prefill (B=1, N ≥ 2^22) land on decoupled; training shapes (B ≥ 8)
+  keep the carry chain at default blocks.
 """
 
 from __future__ import annotations
@@ -96,7 +93,7 @@ class Choice:
     variant: int  # two-pass organization (1 = scan-first, 2 = reduce-first)
     carry_exchange: str  # distributed sums exchange
     reason: str
-    schedule: str = "carry"  # grid org: 'carry'|'decoupled'|'fused'|'tree'
+    schedule: str = "carry"  # grid org: 'carry'|'decoupled'|'tree'
     # The inputs the choice was made from (the explain surface) — filled
     # by ``choose``; excluded from equality so cached/reconstructed
     # Choices with the same outcome still compare equal.
@@ -126,16 +123,15 @@ def explain_schedule(
     n: int,
     cores: int = NUM_CORES,
     block_elems: int = 2048,
-    prefer_fused: bool = True,
 ) -> Decision:
     """``choose_schedule`` with its working shown: the decision, the
-    branch of the four-way rule that fired, and the inputs — emitted as
-    a ``policy.schedule`` trace event."""
+    branch of the rule that fired, and the inputs — emitted as a
+    ``policy.schedule`` trace event."""
     batch = max(int(batch), 1)
     chunks = -(-n // max(block_elems, 1))
     spare = cores // batch  # cores idle under the carry chain
     inputs = dict(batch=batch, n=n, cores=cores, block_elems=block_elems,
-                  chunks=chunks, spare=spare, prefer_fused=prefer_fused)
+                  chunks=chunks, spare=spare)
     if batch >= cores:
         if block_elems >= TREE_BLOCK_ELEMS:
             return Decision(
@@ -149,18 +145,15 @@ def explain_schedule(
             "schedule", "carry",
             f"batch {batch} >= cores {cores}: rows alone fill every core; "
             f"carry chain has the cheapest HBM traffic", inputs).emit()
-    # A parallel-sequence schedule costs extra machinery (a second read,
-    # or the semaphore chain); only worth it when the idle cores can
-    # actually be fed — at least ``spare`` chunks per row (a row inside
-    # one block has nothing to parallelize).
+    # A parallel-sequence schedule costs a second read; only worth it
+    # when the idle cores can actually be fed — at least ``spare`` chunks
+    # per row (a row inside one block has nothing to parallelize).
     if spare >= 2 and chunks >= spare:
-        value = "fused" if prefer_fused else "decoupled"
         return Decision(
-            "schedule", value,
+            "schedule", "decoupled",
             f"batch {batch} < cores {cores} with {chunks} chunks >= "
-            f"{spare} spare cores: spread the row "
-            f"({'single-launch fused' if prefer_fused else 'two-launch decoupled'})",
-            inputs).emit()
+            f"{spare} spare cores: spread the row (two-launch "
+            f"decoupled)", inputs).emit()
     return Decision(
         "schedule", "carry",
         f"batch {batch} < cores {cores} but only {chunks} chunk(s) for "
@@ -173,19 +166,15 @@ def choose_schedule(
     n: int,
     cores: int = NUM_CORES,
     block_elems: int = 2048,
-    prefer_fused: bool = True,
 ) -> str:
     """Kernel grid organization for a (batch, n) scan — see module doc.
 
     ``block_elems`` must be the chunk length the kernel will actually
     tile with — the chunks-per-spare-core test is meaningless against
-    any other block size. ``prefer_fused=False`` picks the two-launch
-    decoupled form over the single-launch fused one for parallel-sequence
-    shapes (e.g. to sidestep the semaphore path on an unvalidated
-    platform; off-TPU the engine falls back by itself).
-    ``explain_schedule`` returns the same decision with its rationale.
+    any other block size. ``explain_schedule`` returns the same decision
+    with its rationale.
     """
-    return explain_schedule(batch, n, cores, block_elems, prefer_fused).value
+    return explain_schedule(batch, n, cores, block_elems).value
 
 
 # Attention (carried-payload fold) thresholds. SPLIT_KV_CHUNKS is the KV
@@ -439,7 +428,7 @@ def choose(
     if n_devices > 1 and carry_bytes * n_devices > 1 << 20:
         exchange = "hillis_permute"
     reason = "bandwidth-bound: cache/VMEM partitioning, reduce-first (SIMD2-P)"
-    if schedule in ("decoupled", "fused"):
-        reason += f"; {schedule} grid (batch < cores, long row)"
+    if schedule == "decoupled":
+        reason += "; decoupled grid (batch < cores, long row)"
     return _emit(Choice(algo, block, 2, exchange, reason, schedule,
                         inputs=inputs))

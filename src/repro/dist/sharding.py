@@ -21,16 +21,19 @@ import threading
 from typing import Any, Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 Pytree = Any
 
-# jax moved shard_map out of experimental across the 0.4.x line; export
-# one resolved symbol so callers (and test subprocesses) don't chase it.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # jax <= 0.4.37
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+
+def make_mesh(shape, axes, *, devices=None) -> Mesh:
+    """A mesh whose axes are all ``Auto``: the compiler propagates
+    shardings from the annotations, as every caller here expects.
+    (``jax.make_mesh`` defaults to ``Explicit`` axes, under which a
+    constant built inside ``shard_map`` or ``jit`` without a mesh
+    context has no sharding to take.)"""
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 # Logical axis -> mesh axes (filtered to the installed mesh's axis names).
 # The data-parallel axes shard 'batch'; the tensor/expert-parallel axis

@@ -3,7 +3,7 @@
 All four scan families are registrations of ONE monoid-generic engine
 (``scan_engine``): each grid organization is written once against a
 kernel-side monoid spec (``core/scan/assoc.KernelSpec``), and a family
-is just a spec + a layout + a back-compat ``ops`` wrapper. Three grid
+is just a spec + a layout + a back-compat ``ops`` wrapper. Four grid
 schedules per family (`schedule=` knob on each ``ops`` wrapper,
 arbitrated by ``core/scan/policy.choose_schedule``):
 
@@ -17,8 +17,10 @@ arbitrated by ``core/scan/policy.choose_schedule``):
                itself spreads across cores (B=1, huge-N serve shapes).
   fused      — the same reduce-then-scan in a SINGLE launch: chunk
                prefixes chained through cross-chunk semaphores, erasing
-               decoupled's second data read. Two-launch fallback under
-               interpret mode / missing semaphore API.
+               decoupled's second data read. Compiled on a TPU only, for
+               grids that fit its semaphore memory; never picked by the
+               policy.
+  tree       — carry's grid with Blelloch's balanced tree in each tile.
 
   scan_engine      — the schedules (written once) + layouts + registry
   scan_blocked     — prefix sum            (sum monoid registration)

@@ -14,7 +14,7 @@ Four grid schedules (see ``core/scan/policy`` module doc):
   * ``schedule="carry"``     — grid-carried total, sequence sequential;
   * ``schedule="decoupled"`` — reduce-then-scan, two launches;
   * ``schedule="fused"``     — reduce-then-scan, single launch chained
-    through cross-chunk semaphores (two-launch fallback off-TPU);
+    through cross-chunk semaphores (compiled TPU only, small grids);
   * ``schedule="tree"``      — carry's grid, work-efficient Blelloch
     sweep inside each tile (§3.3);
   * ``schedule="auto"``      — the policy's batch-vs-cores rule decides.

@@ -17,8 +17,8 @@ architecture:
   schedules.scan_fused      — reduce-then-   (stream compaction, fused
     scan in ONE launch, chunk prefixes       predicate select)
     chained through cross-chunk            assoc.softmax_pair_kernel_spec
-    semaphores (Merrill-style); falls        (flash attention: carried
-    back to two-launch under interpret       payload + input transform)
+    semaphores (Merrill-style); compiled     (flash attention: carried
+    TPU only, never picked by the policy     payload + input transform)
   schedules.scan_tree       — work-efficient
     balanced tree (§3.3, Observation 5):
     Blelloch up-sweep/down-sweep inside
@@ -53,7 +53,6 @@ from repro.kernels.scan_engine.layouts import (Channels, KVBlocks, QBlocks,
 from repro.kernels.scan_engine.schedules import (RESOLVABLE, SCHEDULES,
                                                  exclusive_chain, fold_carry,
                                                  fold_chain, fold_decoupled,
-                                                 fused_native_available,
                                                  resolve_schedule, scan,
                                                  scan_carry, scan_decoupled,
                                                  scan_fused, scan_tree,
@@ -62,7 +61,7 @@ from repro.kernels.scan_engine.schedules import (RESOLVABLE, SCHEDULES,
 __all__ = [
     "Channels", "KVBlocks", "QBlocks", "RESOLVABLE", "Rows", "SCHEDULES",
     "block_live", "exclusive_chain", "fold_carry", "fold_chain",
-    "fold_decoupled", "fused_native_available", "monoids",
+    "fold_decoupled", "monoids",
     "resolve_schedule", "scan", "scan_carry", "scan_decoupled",
     "scan_fused", "scan_tree", "tile_scan", "tree_scan",
 ]

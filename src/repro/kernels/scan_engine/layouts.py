@@ -46,6 +46,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128
+
 
 def _check_divisible(shape, block, what):
     for s, b in zip(shape, block):
@@ -117,16 +119,28 @@ class Rows(_UniformLeaves):
     def data_spec(self):
         return pl.BlockSpec((self.bb, self.bn), lambda i, j: (i, j))
 
+    # Chunk totals are kept as (row blocks, chunks, bb, LANES): every
+    # grid cell's (bb, 1) carry column, replicated across one lane
+    # tile, is a whole trailing (sublane, lane) plane of its own — the
+    # one block shape the TPU compiler accepts for any bb and any chunk
+    # count, and one a DMA may slice. A (bb, 1) column pads to a full
+    # lane tile in HBM and VMEM anyway, so the replication costs no
+    # memory.
     def chain_spec(self):
-        return pl.BlockSpec((self.bb, 1), lambda i, j: (i, j))
+        return pl.BlockSpec(self.chain_block, lambda i, j: (i, j, 0, 0))
 
     @property
     def chain_shape(self):
-        return (self.rows, self.num_seq_blocks)
+        return (self.rows // self.bb, self.num_seq_blocks, self.bb, LANES)
 
     @property
     def chain_block(self):
-        return (self.bb, 1)
+        return (1, 1, self.bb, LANES)
+
+    def unchain(self, x):
+        """Chain array -> (rows, chunks), chunk ``j`` in column ``j``."""
+        nb, nc = x.shape[:2]
+        return x[..., 0].transpose(0, 2, 1).reshape(nb * self.bb, nc)
 
     def carry_scratch(self, dtype, leaf=0):
         return pltpu.VMEM((self.bb, 1), dtype)
@@ -145,20 +159,20 @@ class Rows(_UniformLeaves):
         ref[...] = val.astype(ref.dtype)
 
     def read_chain(self, ref):
-        return ref[...]
+        return ref[0, 0][:, :1]
 
     def write_chain(self, ref, val):
-        ref[...] = val.astype(ref.dtype)
+        ref[0, 0] = jnp.broadcast_to(val, self.chain_block[2:]).astype(
+            ref.dtype)
 
     def take_last(self, x):
         return x[:, -1:]
 
     # -- fused-schedule addressing (whole-array HBM refs) ---------------
     def chain_at(self, ref, seq_index):
-        """Slice one chunk column of the (rows, chunks) chain buffer for
+        """Slice one chunk's (1, 1, bb, LANES) block of the chain buffer for
         this instance's row block."""
-        i = pl.program_id(0)
-        return ref.at[pl.ds(i * self.bb, self.bb), pl.ds(seq_index, 1)]
+        return ref.at[pl.ds(pl.program_id(0), 1), pl.ds(seq_index, 1)]
 
     def sem_at(self, sem, seq_index):
         return sem.at[pl.program_id(0), seq_index]
@@ -205,16 +219,23 @@ class Channels(_UniformLeaves):
     def data_spec(self):
         return pl.BlockSpec((1, self.bt, self.bd), lambda i, d, t: (i, t, d))
 
+    # Chunk totals are kept as (B, chunks, 1, D): the (1, bd) carry row
+    # is a whole (sublane, lane-block) plane, as the compiler requires.
     def chain_spec(self):
-        return pl.BlockSpec((1, 1, self.bd), lambda i, d, t: (i, t, d))
+        return pl.BlockSpec(self.chain_block,
+                            lambda i, d, t: (i, t, 0, d))
 
     @property
     def chain_shape(self):
-        return (self.b, self.num_seq_blocks, self.d)
+        return (self.b, self.num_seq_blocks, 1, self.d)
 
     @property
     def chain_block(self):
-        return (1, 1, self.bd)
+        return (1, 1, 1, self.bd)
+
+    def unchain(self, x):
+        """Chain array -> (B, chunks, D)."""
+        return x[:, :, 0]
 
     def carry_scratch(self, dtype, leaf=0):
         return pltpu.VMEM((1, self.bd), dtype)
@@ -232,17 +253,17 @@ class Channels(_UniformLeaves):
         ref[...] = val.astype(ref.dtype)
 
     def read_chain(self, ref):
-        return ref[0]  # (1, bd)
+        return ref[0, 0]  # (1, bd)
 
     def write_chain(self, ref, val):
-        ref[0] = val.astype(ref.dtype)
+        ref[0, 0] = val.astype(ref.dtype)
 
     def take_last(self, x):
         return x[-1:, :]
 
     def chain_at(self, ref, seq_index):
         i, d = pl.program_id(0), pl.program_id(1)
-        return ref.at[pl.ds(i, 1), pl.ds(seq_index, 1),
+        return ref.at[pl.ds(i, 1), pl.ds(seq_index, 1), :,
                       pl.ds(d * self.bd, self.bd)]
 
     def sem_at(self, sem, seq_index):
@@ -538,10 +559,11 @@ class KVBlocks(_AttnFold):
     # -- cell-count instrumentation (carry fold) -------------------------
     @property
     def count_shape(self):
-        return (self.bh, self.nq)
+        # trailing (1, 1) plane per row: a block the compiler accepts
+        return (self.bh, self.nq, 1, 1)
 
     def count_spec(self):
-        return pl.BlockSpec((1, 1), lambda h, qi, j: (h, qi))
+        return pl.BlockSpec((1, 1, 1, 1), lambda h, qi, j: (h, qi, 0, 0))
 
     # -- in-kernel views -------------------------------------------------
     def block_ids(self):
@@ -636,10 +658,10 @@ class QBlocks(_AttnFold):
     # -- cell-count instrumentation (carry fold) -------------------------
     @property
     def count_shape(self):
-        return (self.bh_kv, self.nk)
+        return (self.bh_kv, self.nk, 1, 1)
 
     def count_spec(self):
-        return pl.BlockSpec((1, 1), lambda h, j, f: (h, j))
+        return pl.BlockSpec((1, 1, 1, 1), lambda h, j, f: (h, j, 0, 0))
 
     # -- in-kernel views -------------------------------------------------
     def block_ids(self):

@@ -17,20 +17,18 @@ segmented, affine-SSM and compact-mask all run the SAME bodies:
   fused      decoupled in a single launch: every chunk computes its local
              scan once, then chains its prefix to its successor through
              cross-chunk semaphores (Merrill-style chained scan). HBM:
-             read n + write n with decoupled's parallelism. Requires the
-             TPU semaphore API; under interpret mode (or when the API is
-             missing) it degrades to the two-launch decoupled schedule —
-             same organization, same bits.
+             read n + write n with decoupled's parallelism. Runs only
+             compiled on a TPU, on grids that fit the chip's semaphore
+             memory; elsewhere it raises. The policy never picks it.
   tree       the paper's work-efficient balanced tree (§3.3, Observation
              5; Blelloch's up-sweep/down-sweep): the carry schedule's
              grid and inter-block carry, but the IN-TILE network replaced
-             by a recursive pairwise up-sweep (combine evens with odds,
-             halving the problem) and down-sweep (parent prefixes fan
-             back out, ``combine(parent, old_left)``). O(n) combines per
-             tile instead of Hillis–Steele's O(n log n), at the price of
-             the strided deinterleave/interleave traffic the paper's
-             Observation 5 charges it with — all inside VMEM, where those
-             extra passes are cheap. HBM: read n + write n.
+             by Blelloch's pairwise up-sweep (each right child combined
+             with its left sibling, doubling the stride) and down-sweep
+             (parent prefixes fan back out, ``combine(parent,
+             old_left)``), written in place as masked strided passes —
+             the access pattern the paper's Observation 5 charges it
+             with, confined to VMEM. HBM: read n + write n.
 
 Bit-identity across schedules holds by construction for every monoid:
 carry/decoupled/fused run the identical in-tile scan network, and the
@@ -61,7 +59,7 @@ carried state via ``spec.finalize``:
              sub-blocks and publishing one payload element to the chain
              buffers; a tiny jnp combine chain + finalize stitches the
              chunks. (No fused form: a fold has no per-element writeback
-             to chain a prefix into, so "fused" maps to decoupled.)
+             to chain a prefix into, so "fused" raises.)
 
 Folds are not bitwise-invariant across schedules — the chunk chain
 re-associates the payload rescaling — but agree to float tolerance, and
@@ -86,10 +84,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.scan import policy
 from repro.core.scan.assoc import KernelSpec
-from repro.kernels import pallas_compat
+from repro.kernels.scan_engine.layouts import LANES
 from repro.obs import trace
-
-LANES = 128
 
 SCHEDULES = ("carry", "decoupled", "fused", "tree")
 RESOLVABLE = SCHEDULES + ("auto",)
@@ -118,11 +114,13 @@ def resolve_schedule(schedule: str, batch: int, n: int,
 
 
 def _shift(x, k, axis, fill):
-    """Shift ``x`` right by ``k`` along ``axis``, filling with identity."""
+    """Shift ``x`` by ``k`` along ``axis`` — right for ``k > 0``, left
+    for ``k < 0`` — filling the vacated slots with identity."""
+    n = x.shape[axis]
     pad = [(0, 0)] * x.ndim
-    pad[axis] = (k, 0)
+    pad[axis] = (k, 0) if k > 0 else (0, -k)
     sl = [slice(None)] * x.ndim
-    sl[axis] = slice(0, x.shape[axis])
+    sl[axis] = slice(0, n) if k > 0 else slice(-k, n - k)
     return jnp.pad(x, pad, constant_values=fill)[tuple(sl)]
 
 
@@ -175,59 +173,27 @@ def _pad_to(x, m, axis, fill):
     return jnp.pad(x, pad, constant_values=fill)
 
 
-def _blelloch(spec: KernelSpec, leaves, axis):
-    """Recursive pairwise Blelloch sweep; power-of-two length required.
-
-    Up-sweep: deinterleave the tile into even/odd positions and combine
-    (``combine(evens, odds)`` — left argument earlier, preserving
-    non-commutative order), recursing on the half-length pair totals.
-    Down-sweep: the recursion returns the parents' EXCLUSIVE prefixes;
-    each even slot takes its parent's prefix unchanged and each odd slot
-    takes ``combine(parent, old_left)`` — the same left-argument
-    convention the ``core.scan.tree`` oracle pins. Returns
-    ``(exclusive_scan, root_total)`` where the total keeps a size-1 scan
-    axis (the shape ``layout.take_last`` produces, so the inter-block
-    carry chain is shared with the carry schedule verbatim).
-
-    The deinterleave/interleave is reshape-based (no gather): at each of
-    the log2(n) levels a ``(..., m/2, 2, ...)`` view splits and a stack +
-    reshape merges — the strided access pattern of the paper's
-    Observation 5, confined to VMEM.
-    """
-    m = leaves[0].shape[axis]
-    if m == 1:
-        ident = tuple(
-            jnp.full_like(x, f) for x, f in zip(leaves, spec.fills))
-        return ident, leaves
-
-    def split(x):
-        shape = x.shape
-        xs = x.reshape(shape[:axis] + (m // 2, 2) + shape[axis + 1:])
-        ev = jax.lax.index_in_dim(xs, 0, axis + 1, keepdims=False)
-        od = jax.lax.index_in_dim(xs, 1, axis + 1, keepdims=False)
-        return ev, od
-
-    pairs = tuple(split(x) for x in leaves)
-    evens = tuple(p[0] for p in pairs)
-    odds = tuple(p[1] for p in pairs)
-    parent_excl, total = _blelloch(spec, spec.combine(evens, odds), axis)
-    right = spec.combine(parent_excl, evens)   # combine(parent, old_left)
-
-    def merge(left, rt):
-        st = jnp.stack([left, rt], axis=axis + 1)
-        return st.reshape(left.shape[:axis] + (m,) + left.shape[axis + 1:])
-
-    excl = tuple(merge(l, r) for l, r in zip(parent_excl, right))
-    return excl, total
-
-
 def tree_scan(spec: KernelSpec, leaves, axis):
     """Work-efficient in-tile EXCLUSIVE scan (§3.3 balanced tree).
 
+    Blelloch's up-sweep/down-sweep written in place over the whole tile,
+    so the compiler sees only shifts, an iota and selects — no reshape
+    of the lane axis. Level ``s`` (1, 2, 4, …) of the up-sweep combines
+    each right child with its left sibling ``s`` slots earlier
+    (``combine(left, right)`` — left argument earlier, preserving
+    non-commutative order); the down-sweep seeds the root with the
+    identity and walks back down, handing each left child its parent's
+    prefix and each right child ``combine(parent, old_left)``, the
+    convention the ``core.scan.tree`` oracle pins. The tree's
+    association is the recursive pairwise one; the masked passes are
+    full-width, log2(n) of them each way, all inside VMEM.
+
     Pads the scan axis to a power of two with the monoid identity (the
     padded tail contributes identity to every prefix and to the root
-    total, so the slice-back is exact), runs the Blelloch sweep, and
-    returns ``(exclusive_scan, total)`` — the inclusive form is one
+    total, so the slice-back is exact). Returns ``(exclusive_scan,
+    total)`` where the total keeps a size-1 scan axis (the shape
+    ``layout.take_last`` produces, so the inter-block carry chain is
+    shared with the carry schedule verbatim) — the inclusive form is one
     ``combine(exclusive, elems)`` away, which the tree body fuses into
     its carry application.
     """
@@ -238,11 +204,36 @@ def tree_scan(spec: KernelSpec, leaves, axis):
     if m != n:
         leaves = tuple(
             _pad_to(x, m, axis, f) for x, f in zip(leaves, spec.fills))
-    excl, total = _blelloch(spec, leaves, axis)
+    pos = jax.lax.broadcasted_iota(jnp.int32, leaves[0].shape, axis)
+
+    def select(mask, new, old):
+        return tuple(jnp.where(mask, a, b) for a, b in zip(new, old))
+
+    def shifted(xs, k):
+        return tuple(_shift(x, k, axis, f) for x, f in zip(xs, spec.fills))
+
+    s = 1
+    while s < m:                                   # up-sweep
+        right = (pos & (2 * s - 1)) == 2 * s - 1
+        leaves = select(right, spec.combine(shifted(leaves, s), leaves),
+                        leaves)
+        s *= 2
+    total = tuple(jax.lax.slice_in_dim(x, m - 1, m, axis=axis)
+                  for x in leaves)
+    root = pos == m - 1
+    xs = select(root, tuple(jnp.full_like(x, f)
+                            for x, f in zip(leaves, spec.fills)), leaves)
+    s = m // 2
+    while s >= 1:                                  # down-sweep
+        phase = pos & (2 * s - 1)
+        parent = shifted(xs, -s)                   # right sibling's slot
+        right = select(phase == 2 * s - 1,
+                       spec.combine(xs, shifted(xs, s)), xs)
+        xs = select(phase == s - 1, parent, right)
+        s //= 2
     if m != n:
-        excl = tuple(
-            jax.lax.slice_in_dim(x, 0, n, axis=axis) for x in excl)
-    return excl, total
+        xs = tuple(jax.lax.slice_in_dim(x, 0, n, axis=axis) for x in xs)
+    return xs, total
 
 
 def exclusive_chain(spec: KernelSpec, totals, axis: int = 1):
@@ -343,7 +334,7 @@ def scan_carry(operands, spec, layout, *, exclusive=False, interpret=False,
            for i, dt in enumerate(elem_dts[:n_tot])],
         scratch_shapes=[layout.carry_scratch(dt, i)
                         for i, dt in enumerate(elem_dts)],
-        compiler_params=pallas_compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=layout.semantics("arbitrary")),
         interpret=interpret,
         name=f"scan_{spec.name}_carry",
@@ -384,7 +375,7 @@ def _apply_body(*refs, spec, layout, elem_dts, n_out, exclusive):
 def scan_decoupled(operands, spec, layout, *, exclusive=False,
                    interpret=False, return_totals=False):
     elem_dts, out_dts = _dtypes(spec, operands)
-    par = pallas_compat.compiler_params(
+    par = pltpu.CompilerParams(
         dimension_semantics=layout.semantics("parallel"))
 
     totals = pl.pallas_call(
@@ -428,26 +419,10 @@ def scan_decoupled(operands, spec, layout, *, exclusive=False,
 # ---------------------------------------------------------------------------
 
 
-# Safety gate for the native single-launch path: it has never executed on
-# real hardware (this container is CPU-only), and its liveness rests on an
-# unverified assumption about Mosaic's parallel sub-grid traversal order.
-# Until someone validates it on a TPU (ROADMAP), EVERY "fused" request —
-# including policy-auto production routes — runs the two-launch decoupled
-# organization, which is bit-identical. Flip to True (or monkeypatch) for
-# the on-TPU validation run.
-FUSED_NATIVE_ENABLED = False
-
-
-def fused_native_available() -> bool:
-    """Whether the single-launch chained scan can actually run here.
-
-    Needs the validation gate open, a real TPU backend (the
-    chained-semaphore protocol has no interpreter support), and a jax
-    that exposes the semaphore API.
-    """
-    return (FUSED_NATIVE_ENABLED
-            and jax.default_backend() == "tpu"
-            and pallas_compat.has_semaphores())
+# The native chain holds one regular semaphore per grid cell, and the
+# chip's semaphore memory is small: compiled against a described v5e, a
+# 384-cell grid fits its 2 KiB and a 448-cell grid is refused.
+FUSED_MAX_CELLS = 256
 
 
 def _fused_body(*refs, spec, layout, elem_dts, n_out, exclusive):
@@ -470,10 +445,10 @@ def _fused_body(*refs, spec, layout, elem_dts, n_out, exclusive):
     @pl.when(j > 0)
     def _await_predecessor():
         # Predecessor signals only after its prefix DMA has landed in HBM.
-        pallas_compat.semaphore_wait(layout.sem_at(sems, j - 1), 1)
+        pltpu.semaphore_wait(layout.sem_at(sems, j - 1), 1)
         for p, s in zip(pref_refs, stage_in):
-            cp = pallas_compat.async_copy(layout.chain_at(p, j - 1), s,
-                                          dsem_in)
+            cp = pltpu.make_async_copy(layout.chain_at(p, j - 1), s,
+                                       dsem_in)
             cp.start()
             cp.wait()
 
@@ -488,10 +463,10 @@ def _fused_body(*refs, spec, layout, elem_dts, n_out, exclusive):
         new_prefix = spec.combine(prefix, total)
         for s, p, v in zip(stage_out, pref_refs, new_prefix):
             layout.write_chain(s, v)
-            cp = pallas_compat.async_copy(s, layout.chain_at(p, j), dsem_out)
+            cp = pltpu.make_async_copy(s, layout.chain_at(p, j), dsem_out)
             cp.start()
             cp.wait()
-        pallas_compat.semaphore_signal(layout.sem_at(sems, j), 1)
+        pltpu.semaphore_signal(layout.sem_at(sems, j), 1)
 
     sel = shift_one(spec, scanned, layout.scan_axis) if exclusive else scanned
     combined = spec.combine(prefix, sel)
@@ -502,25 +477,28 @@ def scan_fused(operands, spec, layout, *, exclusive=False, interpret=False,
                return_totals=False):
     """Single-launch decoupled: chunk prefixes chained through semaphores.
 
-    EXPERIMENTAL on-device path (pending real-TPU validation — see
-    ROADMAP): each grid instance scans its chunk once, waits for its
-    predecessor's published prefix, combines, republishes, and fuses the
-    prefix into its own writeback — read n + write n total, with the
-    scanned axis spread across cores. Correct under Mosaic's ascending
-    per-core traversal of parallel grid dimensions (contiguous slabs or
-    round-robin both chain forward). Until ``FUSED_NATIVE_ENABLED`` is
-    flipped after on-TPU validation — and always off-TPU / under
-    interpret mode — callers get the two-launch decoupled schedule: the
-    same organization split into two ``pallas_call``s, bit-identical
-    results.
+    Each grid instance scans its chunk once, waits for its predecessor's
+    published prefix, combines, republishes, and fuses the prefix into
+    its own writeback — read n + write n total. The chain runs only
+    compiled on a TPU, and only for grids of at most
+    ``FUSED_MAX_CELLS`` cells; anything else raises rather than running
+    another schedule under this one's name. The policy never picks it.
     """
-    if interpret or not fused_native_available() or return_totals:
-        # return_totals also routes here: the native chain buffers hold
-        # per-chunk PREFIXES except the last chunk (which never
-        # publishes), so the two-launch form is the totals-bearing one.
-        return scan_decoupled(operands, spec, layout, exclusive=exclusive,
-                              interpret=interpret,
-                              return_totals=return_totals)
+    if interpret:
+        raise ValueError(
+            "schedule='fused' runs only compiled on a TPU: its "
+            "cross-chunk semaphore chain has no interpret-mode form")
+    if return_totals:
+        raise ValueError(
+            "schedule='fused' publishes chunk prefixes, not running "
+            "totals; use carry, decoupled or tree for return_totals")
+    cells = 1
+    for g in layout.grid:
+        cells *= g
+    if cells > FUSED_MAX_CELLS:
+        raise ValueError(
+            f"schedule='fused' needs one semaphore per grid cell; "
+            f"{cells} cells exceed the chip's {FUSED_MAX_CELLS}")
     elem_dts, out_dts = _dtypes(spec, operands)
     n_elem = spec.n_leaves
     grid = layout.grid
@@ -531,17 +509,17 @@ def scan_fused(operands, spec, layout, *, exclusive=False, interpret=False,
         grid=grid,
         in_specs=layout.op_specs(len(operands)),
         out_specs=[layout.out_spec()] * len(out_dts)
-        + [pl.BlockSpec(memory_space=pallas_compat.any_memory_space())]
+        + [pl.BlockSpec(memory_space=pl.ANY)]
         * n_elem,
         out_shape=[jax.ShapeDtypeStruct(layout.shape, dt) for dt in out_dts]
         + [jax.ShapeDtypeStruct(layout.chain_shape, dt) for dt in elem_dts],
         scratch_shapes=(
             [pltpu.VMEM(layout.chain_block, dt) for dt in elem_dts]
             + [pltpu.VMEM(layout.chain_block, dt) for dt in elem_dts]
-            + [pallas_compat.regular_semaphores(grid),
-               pallas_compat.dma_semaphore(),
-               pallas_compat.dma_semaphore()]),
-        compiler_params=pallas_compat.compiler_params(
+            + [pltpu.SemaphoreType.REGULAR(tuple(grid)),
+               pltpu.SemaphoreType.DMA(()),
+               pltpu.SemaphoreType.DMA(())]),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=layout.semantics("parallel")),
         interpret=interpret,
         name=f"scan_{spec.name}_fused",
@@ -590,9 +568,9 @@ def scan_tree(operands, spec, layout, *, exclusive=False, interpret=False,
               return_totals=False):
     """Carry's grid with the Blelloch tree as the in-tile network.
 
-    Work-efficient (O(n) combines per tile vs Hillis–Steele's
-    O(n log n)) at the cost of log2(n) strided deinterleave/interleave
-    passes inside VMEM — the §3.3 organization. The inter-block carry
+    The §3.3 organization: the tile's combines follow the balanced tree
+    (see ``tree_scan``), at the cost of 2·log2(n) masked strided passes
+    inside VMEM. The inter-block carry
     chain, exclusive handling, and optional chunk-totals chain all match
     ``scan_carry`` exactly, so the schedules differ only in how each
     tile internally associates ``combine``.
@@ -613,7 +591,7 @@ def scan_tree(operands, spec, layout, *, exclusive=False, interpret=False,
            for i, dt in enumerate(elem_dts[:n_tot])],
         scratch_shapes=[layout.carry_scratch(dt, i)
                         for i, dt in enumerate(elem_dts)],
-        compiler_params=pallas_compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=layout.semantics("arbitrary")),
         interpret=interpret,
         name=f"scan_{spec.name}_tree",
@@ -702,7 +680,7 @@ def _fold_carry_body(*refs, spec, layout, elem_dts, n_ops, n_out, count):
         for r, o in zip(out_refs, spec.finalize(cur)):
             layout.write(r, o)
         for r, c in zip(cnt_refs, cnt_scratch):
-            r[0, 0] = c[0, 0]
+            r[0, 0] = c[...]
 
 
 def fold_carry(operands, spec, layout, *, interpret=False,
@@ -731,13 +709,13 @@ def fold_carry(operands, spec, layout, *, interpret=False,
         scratch_shapes=[layout.carry_scratch(dt, i)
                         for i, dt in enumerate(elem_dts)]
         + [pltpu.VMEM((1, 1), jnp.int32)] * count,
-        compiler_params=pallas_compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=layout.semantics("arbitrary")),
         interpret=interpret,
         name=f"scan_{spec.name}_fold_carry",
     )(*operands)
     if count_cells:
-        return tuple(outs[:-1]), outs[-1]
+        return tuple(outs[:-1]), outs[-1][..., 0, 0]
     return tuple(outs)
 
 
@@ -787,7 +765,7 @@ def fold_decoupled(operands, spec, layout, *, interpret=False):
                    for i, dt in enumerate(elem_dts)],
         scratch_shapes=[layout.carry_scratch(dt, i)
                         for i, dt in enumerate(elem_dts)],
-        compiler_params=pallas_compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=layout.split_semantics()),
         interpret=interpret,
         name=f"scan_{spec.name}_fold_totals",
@@ -869,10 +847,10 @@ def scan(operands, spec: KernelSpec, layout, *, schedule: str = "carry",
     fold.
 
     Carried-payload monoids (``spec.transform``) run the fold forms of
-    the schedules; ``fused`` maps to ``decoupled`` there (a fold has no
-    per-element writeback to chain a prefix into) and ``tree`` maps to
-    the carry fold (a fold consumes one macro element per grid block —
-    there is no in-block element axis for the tree sweep to reorganize).
+    the schedules; ``fused`` raises there (a fold has no per-element
+    writeback to chain a prefix into) and ``tree`` maps to the carry fold
+    (a fold consumes one macro element per grid block — there is no
+    in-block element axis for the tree sweep to reorganize).
     ``count_cells=True`` (carry fold only) additionally returns the
     executed-cell counts — the causal-bound instrumentation.
     """
@@ -891,6 +869,10 @@ def scan(operands, spec: KernelSpec, layout, *, schedule: str = "carry",
             raise ValueError(
                 "return_totals is meaningless for carried-payload "
                 "monoids: the output IS the fold")
+        if schedule == "fused":
+            raise ValueError(
+                "carried-payload folds have no fused form; use carry or "
+                "decoupled")
         if schedule in ("carry", "tree"):
             return fold_carry(tuple(operands), spec, layout,
                               interpret=interpret, count_cells=count_cells)
@@ -898,5 +880,9 @@ def scan(operands, spec: KernelSpec, layout, *, schedule: str = "carry",
                               interpret=interpret)
     fn = {"carry": scan_carry, "decoupled": scan_decoupled,
           "fused": scan_fused, "tree": scan_tree}[schedule]
-    return fn(tuple(operands), spec, layout, exclusive=exclusive,
-              interpret=interpret, return_totals=return_totals)
+    res = fn(tuple(operands), spec, layout, exclusive=exclusive,
+             interpret=interpret, return_totals=return_totals)
+    if return_totals:
+        outs, totals = res
+        return outs, tuple(layout.unchain(t) for t in totals)
+    return res

@@ -41,7 +41,7 @@ from repro.train.step import TrainStepConfig, make_train_step, shardings_for
 def make_mesh(name: str):
     if name == "single":
         devices = jax.devices()[:256]
-        return jax.make_mesh((16, 16), ("data", "model"), devices=devices)
+        return shd.make_mesh((16, 16), ("data", "model"), devices=devices)
     if name == "multi":
         return meshlib.make_production_mesh(multi_pod=True)
     raise ValueError(name)
@@ -142,7 +142,8 @@ def lower_cell(arch: str, shape: str, mesh_name: str,
 
     report = analyze_compiled(
         compiled, arch=arch, shape=shape, mesh_name=mesh_name, chips=chips,
-        cfg=cfg, batch=sp.global_batch, seq=sp.seq_len, kind=kind)
+        cfg=cfg, batch=sp.global_batch, seq=sp.seq_len, kind=kind,
+        device_kind=meshlib.TARGET_KIND)
     return lowered, compiled, report
 
 

@@ -7,7 +7,10 @@ Hardening knobs ride along: ``--fault-seed`` runs the request mix under
 the deterministic chaos injector (transient errors / NaN logits /
 stalls), ``--deadline-ticks``/``--max-waiting`` exercise admission
 control and TTLs, and the run always ends with the ``EngineStats``
-health line the chaos tests assert on.
+health line the chaos tests assert on. Without an injector the exit code
+is non-zero when any request finished ``error`` or ``rejected`` or the
+engine had to retry, roll back or degrade a step: on a healthy run none
+of that happens, and a recovery would hide a failing kernel.
 
 Observability knobs: ``--trace out.json`` captures the run as a Chrome
 ``trace_event`` file (open in ui.perfetto.dev — tick/prefill/decode
@@ -27,6 +30,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import Registry, trace
 from repro.serve import Engine, EngineConfig, FaultInjector, Request
 from repro.train.step import init_params
@@ -78,6 +82,7 @@ def main(argv=None):
                     help="print stats as one machine-parsable JSON line")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     if args.trace is not None:
         trace.enable()
 
@@ -145,6 +150,14 @@ def main(argv=None):
     if args.trace is not None:
         n = len(trace.export(args.trace)["traceEvents"])
         print(f"trace: {n} events -> {args.trace}")
+    if injector is None:
+        failed = {r.rid: r.finish_reason for r in done
+                  if r.finish_reason in ("error", "rejected")}
+        recoveries = eng.stats.recoveries()
+        if failed or recoveries:
+            print(f"UNHEALTHY: failed finishes {failed}, "
+                  f"recoveries {recoveries}")
+            return 1
     return 0
 
 

@@ -3,10 +3,11 @@
 Two modes:
   * ``--smoke`` (CPU): reduced config, real optimization for N steps with
     checkpointing — the end-to-end path tests/examples use.
-  * production (TPU pods): full config on the production mesh; the same
-    code path the dry-run lowers, with real data wiring left to the
-    deployment (synthetic stream by default so the binary is self-
-    contained).
+  * full config on a mesh over the devices present — the production
+    16×16 (×2 pods) layout on a full pod, data parallel over whatever is
+    there otherwise (one chip runs it too); the same code path the
+    dry-run lowers, with real data wiring left to the deployment
+    (synthetic stream by default so the binary is self-contained).
 
     PYTHONPATH=src python -m repro.launch.train --arch xlstm-125m \
         --smoke --steps 50
@@ -16,6 +17,8 @@ from __future__ import annotations
 
 import argparse
 import logging
+import os
+import tempfile
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +27,7 @@ from repro import configs
 from repro.data import DataConfig, SyntheticDataset
 from repro.dist import sharding as shd
 from repro.launch import mesh as meshlib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import adamw_init
 from repro.train.step import (TrainStepConfig, init_params, make_train_step,
                               shardings_for)
@@ -40,13 +44,18 @@ def main(argv=None):
     ap.add_argument("--global-batch", type=int, default=8)
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--peak-lr", type=float, default=3e-4)
-    ap.add_argument("--checkpoint-dir", default="/tmp/repro_train")
+    ap.add_argument("--checkpoint-dir", default=None,
+                    help="default: repro_train under the temp directory")
     ap.add_argument("--checkpoint-every", type=int, default=100)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    if args.checkpoint_dir is None:
+        args.checkpoint_dir = os.path.join(tempfile.gettempdir(),
+                                           "repro_train")
 
+    enable_compile_cache()
     logging.basicConfig(
         level=logging.INFO,
         format="%(asctime)s %(name)s %(message)s")
@@ -75,11 +84,11 @@ def main(argv=None):
         opt = adamw_init(params)
         jitted = jax.jit(step_fn, donate_argnums=(0, 1))
     else:
-        mesh = meshlib.make_production_mesh(multi_pod=args.multi_pod)
+        mesh = meshlib.make_device_mesh(multi_pod=args.multi_pod)
         with shd.use_mesh(mesh):
             params_s, opt_s = jax.eval_shape(
                 lambda k: (lambda p: (p, None))(init_params(k, cfg)), key)
-            batch_like = dict(SyntheticDataset(dcfg).batch_at(0))
+            batch_like = SyntheticDataset(dcfg).batch_at(0)
             in_sh, out_sh = shardings_for(mesh, params_s, None, batch_like)
             params = jax.jit(
                 lambda k: init_params(k, cfg), out_shardings=in_sh[0])(key)

@@ -224,9 +224,13 @@ def analyze_compiled(
     batch: int,
     seq: int,
     kind: str,
+    device_kind: str,
     hlo_text: Optional[str] = None,
     chips_per_pod: int = 256,
 ) -> RooflineReport:
+    """``device_kind`` names the chip the program is costed for — its
+    peaks come from ``launch.mesh.PEAKS``, and an unknown kind raises."""
+    pk = meshlib.peaks(device_kind)
     cost = compiled.cost_analysis()
     if isinstance(cost, list):
         cost = cost[0]
@@ -240,10 +244,10 @@ def analyze_compiled(
 
     # cost_analysis numbers are PER-DEVICE (see module docstring): compare
     # against per-chip peak rates directly.
-    compute_s = flops / meshlib.PEAK_FLOPS_BF16
-    memory_s = nbytes / meshlib.HBM_BW
-    collective_s = coll_total / (meshlib.ICI_BW * meshlib.ICI_LINKS)
-    dcn_s = xpod / meshlib.DCN_BW
+    compute_s = flops / pk.flops_bf16
+    memory_s = nbytes / pk.hbm_bw
+    collective_s = coll_total / (pk.ici_bw * pk.ici_links)
+    dcn_s = xpod / pk.dcn_bw
 
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s, "dcn": dcn_s}

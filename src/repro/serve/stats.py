@@ -27,6 +27,13 @@ FINISH_REASONS = (
 )
 
 
+#: Counters a healthy run leaves at 0: each counts a failure the engine
+#: recovered from by retrying, bisecting, rolling back or degrading to
+#: the reference route — recoveries that would hide a failing kernel.
+RECOVERY_COUNTERS = ("step_retries", "prefill_retries", "quarantined",
+                     "nonfinite_ticks", "degradations", "skipped_ticks")
+
+
 @dataclasses.dataclass
 class EngineStats:
     """Monotonic counters plus current queue gauges.
@@ -114,6 +121,11 @@ class EngineStats:
         if reg is not None:
             reg.counter(f"serve.finished.{reason}").inc()
             reg.gauge("serve.stats.total_finished").set(self.total_finished)
+
+    def recoveries(self) -> Dict[str, int]:
+        """The nonzero ``RECOVERY_COUNTERS`` (empty on a healthy run)."""
+        return {k: getattr(self, k) for k in RECOVERY_COUNTERS
+                if getattr(self, k)}
 
     def observe_queue(self, depth: int) -> None:
         self.queue_depth = depth

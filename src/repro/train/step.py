@@ -162,10 +162,11 @@ def shardings_for(mesh: Mesh, params: Pytree, opt_state: Any,
         step=NamedSharding(mesh, P()),
         mu=pshard, nu=pshard, master=pshard)
     bspec = make_batch_specs(mesh)
-    bshard = {
+    # same container type as the batch, so the trees match under jit
+    bshard = type(batch_like)({
         k: NamedSharding(mesh, P(*([bspec[0]] + [None] * (v.ndim - 1))))
         if getattr(v, "ndim", 0) else NamedSharding(mesh, P())
-        for k, v in batch_like.items()}
+        for k, v in batch_like.items()})
     mshard = NamedSharding(mesh, P())
     in_sh = (pshard, oshard, bshard, mshard)
     out_sh = (pshard, oshard, None)

@@ -1,27 +1,5 @@
-import sys
-
 import numpy as np
 import pytest
-
-try:  # gate the optional property-testing dep (container may lack it)
-    import hypothesis  # noqa: F401
-except ImportError:
-    import os
-    import types
-
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    import _hypothesis_fallback as _hf
-
-    mod = types.ModuleType("hypothesis")
-    mod.given = _hf.given
-    mod.settings = _hf.settings
-    strategies = types.ModuleType("hypothesis.strategies")
-    for name in ("floats", "integers", "booleans", "sampled_from", "lists",
-                 "tuples"):
-        setattr(strategies, name, getattr(_hf, name))
-    mod.strategies = strategies
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = strategies
 
 
 def pytest_configure(config):

@@ -162,11 +162,9 @@ def test_ssm_decoupled_block_invariance_and_bit_identity(block_t):
 
 def test_policy_schedule_rule():
     # serve/decode class: one long row -> parallel sequence (Obs 3); the
-    # single-launch fused form is preferred, two-launch on request
-    assert policy.choose_schedule(1, 1 << 22) == "fused"
-    assert policy.choose_schedule(1, 1 << 22, prefer_fused=False) \
-        == "decoupled"
-    assert policy.choose(1 << 22, batch=1).schedule == "fused"
+    # policy never picks the semaphore-bound fused form
+    assert policy.choose_schedule(1, 1 << 22) == "decoupled"
+    assert policy.choose(1 << 22, batch=1).schedule == "decoupled"
     # training class: rows fill the cores -> carry chain (Obs 2)
     assert policy.choose_schedule(policy.NUM_CORES, 1 << 22) == "carry"
     assert policy.choose(1 << 22, batch=64).schedule == "carry"
@@ -177,7 +175,7 @@ def test_policy_schedule_rule():
 
 
 def test_ops_auto_schedule_routes_by_shape():
-    assert sb_ops.resolve_schedule("auto", 1, 1 << 22, 2048) == "fused"
+    assert sb_ops.resolve_schedule("auto", 1, 1 << 22, 2048) == "decoupled"
     assert sb_ops.resolve_schedule("auto", 64, 1 << 22, 2048) == "carry"
     assert sb_ops.resolve_schedule("carry", 1, 1 << 22, 2048) == "carry"
     assert sb_ops.resolve_schedule("decoupled", 64, 1 << 22, 2048) \

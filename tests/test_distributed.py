@@ -18,11 +18,9 @@ def _run(body: str, devices: int = 8, timeout: int = 420):
             "--xla_force_host_platform_device_count={devices}")
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        if not hasattr(jax, "shard_map"):  # jax <= 0.4.37 compat
-            from repro.dist.sharding import shard_map as _sm
-            jax.shard_map = _sm
+        from repro.dist.sharding import make_mesh
     """) + textwrap.dedent(body)
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=os.path.join(_ROOT, "src"))
     res = subprocess.run(
         [sys.executable, "-c", prog], capture_output=True, text=True,
@@ -38,7 +36,7 @@ def test_scan_sharded_matches_ref(variant, exchange):
     """The paper's multithreaded two-pass scan with devices as threads."""
     out = _run(f"""
         from repro.core import scan as scanlib
-        mesh = jax.make_mesh((8,), ("d",))
+        mesh = make_mesh((8,), ("d",))
         x = jnp.asarray(
             np.random.default_rng(0).standard_normal(4096), jnp.float32)
         spec = P("d")
@@ -58,7 +56,7 @@ def test_scan_sharded_affine_monoid():
     """Distributed SSM-style affine scan (sequence parallelism carry)."""
     out = _run("""
         from repro.core import scan as scanlib
-        mesh = jax.make_mesh((4,), ("d",))
+        mesh = make_mesh((4,), ("d",))
         rng = np.random.default_rng(1)
         a = jnp.asarray(rng.uniform(0.8, 1.0, (512,)), jnp.float32)
         b = jnp.asarray(rng.standard_normal((512,)), jnp.float32)
@@ -83,7 +81,7 @@ def test_scan_sharded_affine_monoid():
 def test_scan_sharded_exclusive():
     out = _run("""
         from repro.core import scan as scanlib
-        mesh = jax.make_mesh((8,), ("d",))
+        mesh = make_mesh((8,), ("d",))
         x = jnp.arange(1, 257, dtype=jnp.float32)
         spec = P("d")
         xs = jax.device_put(x, NamedSharding(mesh, spec))
@@ -100,7 +98,7 @@ def test_compressed_psum_error_feedback():
     """int8 gradient compression: biased per step, unbiased with EF."""
     out = _run("""
         from repro.optim.compression import compressed_psum
-        mesh = jax.make_mesh((4,), ("d",))
+        mesh = make_mesh((4,), ("d",))
         rng = np.random.default_rng(0)
         g = jnp.asarray(rng.standard_normal((4, 64)), jnp.float32)
 
@@ -140,7 +138,7 @@ def test_dryrun_cell_small_mesh():
                                       shardings_for, init_params)
         from repro.optim import adamw_init
         cfg = configs.get_smoke_config("granite-moe-1b-a400m")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         key = jax.random.PRNGKey(0)
         params_s = jax.eval_shape(lambda k: init_params(k, cfg), key)
         opt_s = jax.eval_shape(adamw_init, params_s)
@@ -193,7 +191,7 @@ def test_distributed_train_step_executes():
         # single device reference
         _, _, m_ref = jax.jit(step)(params, opt, batch, jnp.asarray(0))
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         with shd.use_mesh(mesh):
             in_sh, out_sh = shardings_for(mesh, params, opt, batch)
             jstep = jax.jit(step, in_shardings=in_sh, out_shardings=out_sh)
@@ -228,7 +226,7 @@ def test_sharded_moe_dispatch_on_mesh():
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, 32))
         y_ref, aux_ref = apply_moe(params, x, cfg)   # no mesh -> G=1
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         with shd.use_mesh(mesh):
             y_sh, aux_sh = jax.jit(
                 lambda p, v: apply_moe(p, v, cfg))(params, x)

@@ -73,6 +73,10 @@ def test_long_500k_only_for_subquadratic():
 
 def test_production_mesh_constants():
     from repro.launch import mesh as m
-    assert m.PEAK_FLOPS_BF16 == 197e12
-    assert m.HBM_BW == 819e9
-    assert m.ICI_BW == 50e9
+    v5e = m.peaks("TPU v5 lite")
+    assert v5e.flops_bf16 == 197e12
+    assert v5e.hbm_bw == 819e9
+    assert v5e.ici_bw == 50e9
+    assert "TPU v5e" in v5e.source
+    with pytest.raises(ValueError):
+        m.peaks("cpu")

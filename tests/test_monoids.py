@@ -122,8 +122,7 @@ def test_segmented_lift_matches_python(pairs):
 
 # ---------------------------------------------------------------------------
 # registry-wide law sweep: every entry in assoc.REGISTRY, including
-# SOFTMAX_PAIR and MATRIX_AFFINE (runs under tests/_hypothesis_fallback.py
-# when the real hypothesis is absent)
+# SOFTMAX_PAIR and MATRIX_AFFINE
 # ---------------------------------------------------------------------------
 
 

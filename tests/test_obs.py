@@ -259,10 +259,8 @@ def test_explain_schedule_branches_and_boundaries():
     d = policy.explain_schedule(cores - 1, 1 << 20, cores=cores)
     assert d.value == "carry"  # spare = 8//7 = 1 < 2: nothing to feed
     d = policy.explain_schedule(1, 1 << 20, cores=cores)
-    assert d.value == "fused" and "spread the row" in d.reason
+    assert d.value == "decoupled" and "spread the row" in d.reason
     assert d.inputs["spare"] == cores
-    d = policy.explain_schedule(1, 1 << 20, cores=cores, prefer_fused=False)
-    assert d.value == "decoupled"
     # Short row: chunks < spare cores.
     d = policy.explain_schedule(1, 1024, cores=cores, block_elems=2048)
     assert d.value == "carry" and "nothing to spread" in d.reason
@@ -307,7 +305,7 @@ def test_policy_decisions_emit_trace_events(tracer):
     assert "policy.attention_schedule" in names
     assert "policy.choose" in names
     ev = next(e for e in tracer.events() if e["name"] == "policy.schedule")
-    assert ev["args"]["value"] == "fused"
+    assert ev["args"]["value"] == "decoupled"
     assert ev["args"]["batch"] == 1 and "reason" in ev["args"]
 
 

@@ -92,7 +92,14 @@ def test_end_to_end_tiny_lowering():
         params_s, opt_s, batch, jax.ShapeDtypeStruct((), jnp.int32)
     ).compile()
     rep = analyze_compiled(comp, arch="xlstm-125m", shape="t", mesh_name="1",
-                           chips=1, cfg=cfg, batch=2, seq=32, kind="train")
+                           chips=1, cfg=cfg, batch=2, seq=32, kind="train",
+                           device_kind="TPU v5 lite")
     assert rep.compute_s > 0 and rep.memory_s > 0
     assert rep.dominant in ("compute", "memory", "collective", "dcn")
     assert 0 < rep.useful_ratio
+    # The peaks belong to a named chip: a kind with no table is an error,
+    # never a silent v5e default.
+    with pytest.raises(ValueError, match="no peak table"):
+        analyze_compiled(comp, arch="xlstm-125m", shape="t", mesh_name="1",
+                         chips=1, cfg=cfg, batch=2, seq=32, kind="train",
+                         device_kind="cpu")

@@ -159,7 +159,7 @@ def test_ssm_grad_per_schedule():
         return jnp.sum(h * h)
 
     grads = [jax.grad(loss, argnums=(0, 1))(a, b, s)
-             for s in ("carry", "decoupled", "fused", "tree")]
+             for s in ("carry", "decoupled", "tree")]
     for ga, gb in grads[1:]:
         np.testing.assert_allclose(np.asarray(ga), np.asarray(grads[0][0]),
                                    rtol=1e-4, atol=1e-4)

@@ -1,16 +1,16 @@
 """Monoid-generic scan engine: schedule parity, policy boundaries.
 
 The acceptance bar for the engine refactor (interpret mode on CPU):
-  * carry / decoupled / fused return BIT-identical results for all four
+  * carry / decoupled return BIT-identical results for all four
     registered monoids across dtypes — the paper's organization/operator
     split holds exactly, not just approximately;
   * the tree schedule (Blelloch in-tile sweep) is bitwise identical to
-    the other three wherever ``combine`` is associative in machine
+    the other two wherever ``combine`` is associative in machine
     arithmetic — integers, and floats on exactly-representable data —
     and agrees to float tolerance on arbitrary normals (its balanced
     tree associates differently, so bitwise equality on arbitrary
     floats is mathematically impossible, not an implementation gap);
-  * the four-way ``policy.choose_schedule`` rule at its boundaries
+  * the ``policy.choose_schedule`` rule at its boundaries
     (batch == cores, single-block rows, the tree block threshold,
     itemsize mixes);
   * the engine registry covers the five families and the library monoids
@@ -29,11 +29,12 @@ from repro.kernels.scan_engine import monoids
 from repro.kernels.segscan import ops as seg_ops
 from repro.kernels.ssm_scan import ops as ssm_ops
 
-# The trio whose in-tile network is shared — bitwise on ANY data.
-SCHEDULES = ("carry", "decoupled", "fused")
-# All four — bitwise on exactly-representable data (the tree's different
-# association is exact there).
-SCHEDULES4 = ("carry", "decoupled", "fused", "tree")
+# The pair whose in-tile network is shared — bitwise on ANY data. (The
+# fused schedule shares it too, but runs only compiled on a TPU.)
+SCHEDULES = ("carry", "decoupled")
+# With the tree — bitwise on exactly-representable data (the tree's
+# different association is exact there).
+SCHEDULES4 = ("carry", "decoupled", "tree")
 
 
 def _all_bit_identical(outs):
@@ -44,7 +45,7 @@ def _all_bit_identical(outs):
 
 
 # ---------------------------------------------------------------------------
-# schedule-parity sweep: 3 schedules x 4 monoids x dtypes, bitwise
+# schedule-parity sweep: 2 schedules x 4 monoids x dtypes, bitwise
 # ---------------------------------------------------------------------------
 
 
@@ -125,14 +126,14 @@ def test_parity_mask():
 
 
 # ---------------------------------------------------------------------------
-# 4-schedule parity (tree included) on exact data, + tree float tolerance
+# parity with the tree on exact data, + tree float tolerance
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("exclusive", [False, True])
 def test_parity4_sum_exact(dtype, exclusive):
-    """All FOUR schedules bitwise on integer-valued data — f32/bf16
+    """All three schedules bitwise on integer-valued data — f32/bf16
     included, since small integers are exactly representable and the
     engine widens bf16 accumulation to f32."""
     rng = np.random.default_rng(20)
@@ -247,20 +248,30 @@ def test_segmented_messy_flags_match_reference():
 
 
 def test_fused_falls_back_to_decoupled():
-    """Whenever the native single-launch path can't (or mustn't) run —
-    interpret mode, no TPU, or the validation gate still closed — the
-    fused schedule must run the two-launch decoupled organization: same
-    bits, no semaphore path."""
+    """It does not, any more: the fused schedule's semaphore chain runs
+    only compiled on a TPU, on grids that fit the chip's semaphore
+    memory. Anywhere else a fused request raises — it never runs
+    decoupled under fused's name — and folds, which have no fused form,
+    refuse it too."""
     from repro.kernels.scan_engine import schedules
-    # the native path stays gated off until validated on real TPU (ROADMAP)
-    assert not schedules.FUSED_NATIVE_ENABLED
     x = jnp.asarray(
         np.random.default_rng(4).standard_normal((1, 8192)), jnp.float32)
-    # interpret=True forces the fallback on every backend
-    fused = sb_ops.cumsum(x, interpret=True, schedule="fused", block_n=1024)
-    dec = sb_ops.cumsum(x, interpret=True, schedule="decoupled",
-                        block_n=1024)
-    assert bool(jnp.all(fused == dec))
+    with pytest.raises(ValueError, match="interpret"):
+        sb_ops.cumsum(x, interpret=True, schedule="fused", block_n=1024)
+    big = scan_engine.Rows(1, (schedules.FUSED_MAX_CELLS + 1) * 128, 1, 128)
+    xb = jnp.zeros(big.shape, jnp.float32)
+    with pytest.raises(ValueError, match="semaphore"):
+        scan_engine.scan((xb,), monoids.SUM, big, schedule="fused")
+    lay = scan_engine.Rows(1, 8192, 1, 1024)
+    with pytest.raises(ValueError, match="running"):
+        scan_engine.scan((x,), monoids.SUM, lay, schedule="fused",
+                         return_totals=True)
+    q = jnp.ones((2, 128, 16), jnp.float32)
+    kv = scan_engine.KVBlocks(bh=2, bh_kv=2, tq=128, tk=128, d=16,
+                              bq=128, bk=64)
+    with pytest.raises(ValueError, match="no fused form"):
+        scan_engine.scan((q, q, q), monoids.softmax_pair(), kv,
+                         schedule="fused", interpret=True)
 
 
 # ---------------------------------------------------------------------------
@@ -279,7 +290,7 @@ def test_registry_covers_five_families():
 
 def test_totals_chain_bitwise_across_schedules():
     """``scan(..., return_totals=True)`` returns the RUNNING chunk-totals
-    chain (combined through chunk j): identical bits under all FOUR
+    chain (combined through chunk j): identical bits under all three
     schedules (integer data, so the tree is exact too), last column ==
     the row reduction — what ``mask_compact`` uses for O(B·chunks)
     survivor counts (ROADMAP follow-up)."""
@@ -333,7 +344,7 @@ def test_engine_rejects_unknown_schedule_and_bad_exclusive():
 
 
 # ---------------------------------------------------------------------------
-# policy boundaries (four-way choose_schedule)
+# policy boundaries (choose_schedule)
 # ---------------------------------------------------------------------------
 
 
@@ -345,9 +356,7 @@ def test_choose_schedule_batch_boundary():
     # one fewer row: spare = cores // (cores-1) == 1 < 2 -> still carry
     assert policy.choose_schedule(cores - 1, n) == "carry"
     # half the cores busy -> parallel-sequence schedule
-    assert policy.choose_schedule(cores // 2, n) == "fused"
-    assert policy.choose_schedule(cores // 2, n, prefer_fused=False) \
-        == "decoupled"
+    assert policy.choose_schedule(cores // 2, n) == "decoupled"
 
 
 def test_choose_schedule_single_block_rows():
@@ -358,7 +367,7 @@ def test_choose_schedule_single_block_rows():
     assert policy.choose_schedule(1, 8192, block_elems=2048) == "carry"
     # exactly spare chunks -> flip
     n = policy.NUM_CORES * 2048
-    assert policy.choose_schedule(1, n, block_elems=2048) == "fused"
+    assert policy.choose_schedule(1, n, block_elems=2048) == "decoupled"
 
 
 def test_choose_schedule_tree_boundary():
@@ -381,7 +390,7 @@ def test_choose_schedule_tree_boundary():
     # under-subscribed rows never pick tree, whatever the block size
     assert policy.choose_schedule(cores // 2, n,
                                   block_elems=policy.TREE_BLOCK_ELEMS) \
-        == "fused"
+        == "decoupled"
     d = policy.explain_schedule(cores, n,
                                 block_elems=policy.TREE_BLOCK_ELEMS)
     assert d.value == "tree" and "block_elems" in d.reason
@@ -397,8 +406,8 @@ def test_choose_itemsize_mixes(itemsize):
         assert choice.algorithm == "horizontal"
     else:
         assert choice.algorithm == "kernel"
-        assert choice.schedule == "fused"
-    assert policy.choose_schedule(1, n) == "fused"
+        assert choice.schedule == "decoupled"
+    assert policy.choose_schedule(1, n) == "decoupled"
 
 
 def test_schedule_threaded_through_api():
@@ -407,6 +416,6 @@ def test_schedule_threaded_through_api():
     x = jnp.asarray(
         np.random.default_rng(5).standard_normal(4096), jnp.float32)
     got = scanlib.scan(x, "sum", algorithm="kernel", interpret=True,
-                       schedule="fused")
+                       schedule="decoupled")
     np.testing.assert_allclose(np.asarray(got), np.cumsum(np.asarray(x)),
                                rtol=2e-4, atol=2e-4)

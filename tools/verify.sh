@@ -33,11 +33,13 @@ for name in mods:
         failed.append(name)
         traceback.print_exc()
 print(f"imported {len(mods) - len(failed)}/{len(mods)} modules")
-# dryrun gets a subprocess so its XLA_FLAGS mutation can't leak here
+# dryrun gets a subprocess so its XLA_FLAGS mutation can't leak here;
+# the child stays on the CPU, since this process may hold the chip
 import subprocess
 r = subprocess.run(
     [sys.executable, "-c", "import repro.launch.dryrun"],
-    env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin"})
+    env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin",
+         "JAX_PLATFORMS": "cpu"})
 if r.returncode:
     failed.append("repro.launch.dryrun")
 if failed:
@@ -74,11 +76,11 @@ cells = {
                                       block_n=256),
 }
 for name, fn in cells.items():
-    outs = [fn(s) for s in ("carry", "decoupled", "fused")]
+    outs = [fn(s) for s in ("carry", "decoupled")]
     ok = all(all(bool(jnp.all(p == q)) for p, q in zip(outs[0], o))
              for o in outs[1:])
     assert ok, f"{name}: schedules diverged"
-    print(f"  {name}: carry == decoupled == fused (bitwise)")
+    print(f"  {name}: carry == decoupled (bitwise)")
 
 # The tree schedule associates differently, so its bitwise bar is
 # exact data: integers (and the mask monoid, which is integral).
