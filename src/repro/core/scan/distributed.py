@@ -107,13 +107,6 @@ _EXCHANGES = {
 
 
 def _local_scan(xs, monoid, algorithm, block_size):
-    if algorithm == "kernel":
-        # the engine's Pallas prefix sum, one launch per device
-        if monoid.name != "sum":
-            raise ValueError("local_algorithm='kernel' scans the sum "
-                             f"monoid only, not {monoid.name!r}")
-        from repro.kernels.scan_blocked import ops as kernel_ops
-        return jax.tree.map(lambda x: kernel_ops.cumsum(x, axis=0), xs)
     if algorithm == "blocked":
         return blocked.scan_blocked(xs, monoid, axis=0, block_size=block_size)
     if algorithm == "horizontal":
@@ -147,8 +140,7 @@ def scan_sharded(
       carry_exchange: collective schedule for the `sums` array (see module
         docstring).
       local_algorithm: per-shard algorithm; "blocked" = the paper's
-        cache-friendly partitioning *within* each device, "kernel" = the
-        engine's Pallas prefix sum (sum monoid only).
+        cache-friendly partitioning *within* each device.
     """
     if variant not in (1, 2):
         raise ValueError("variant must be 1 or 2")
@@ -193,12 +185,7 @@ def scan_sharded(
             )
         return jax.tree.map(lambda x: jnp.moveaxis(x, 0, scan_axis), out)
 
-    # check_vma=False: a Pallas local scan declares its outputs without
-    # a varying-axes annotation, which the checker would refuse.
-    fn = jax.shard_map(
-        local_fn, mesh=mesh, in_specs=(spec,), out_specs=spec,
-        check_vma=False,
-    )
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=(spec,), out_specs=spec)
     return fn(elems)
 
 

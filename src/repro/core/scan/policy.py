@@ -17,7 +17,7 @@ can pick a sensible default, and so the choice is documented in one place:
          remain available for study and as oracles.
 
 Kernel SCHEDULE rule (Obs 2/3 applied to the Pallas grid): the kernel-
-backed scans run one of FOUR grid organizations, picked by
+backed scans run one of FOUR grid organizations, two of them picked by
 ``choose_schedule`` (also surfaced as ``Choice.schedule``) and executed
 by the monoid-generic engine in ``repro.kernels.scan_engine``:
 
@@ -39,23 +39,21 @@ by the monoid-generic engine in ``repro.kernels.scan_engine``:
                needs one semaphore per grid cell, and the chip's
                semaphore memory holds a few hundred, far short of the
                chunk counts of the long rows it was meant for.
-  'tree'       carry's grid with the work-efficient Blelloch sweep as the
-               in-tile network (the paper's §3.3 balanced tree): O(b)
-               combines per b-element tile instead of the log network's
-               O(b log b), at the cost of strided deinterleave/interleave
-               passes inside VMEM (Observation 5's memory-access penalty,
-               which partitioning confines to fast memory). Same HBM
-               traffic as carry (read n + write n).
+  'tree'       carry's grid with Blelloch's up-sweep/down-sweep as the
+               in-tile network (the paper's §3.3 balanced tree). Never
+               picked here: written for the chip it is 2·log2(b)
+               full-width masked passes per b-element tile, each a
+               combine plus selects — about twice the work of the
+               Hillis–Steele network carry runs — and no chip cell has
+               shown it winning. Same HBM traffic as carry (read n +
+               write n).
 
   The flip: carry-chain when ``batch >= cores`` (enough rows to fill the
-  machine; cheapest traffic) — upgraded to the tree network when the tile
-  is long (``block_elems >= TREE_BLOCK_ELEMS``), where the in-tile
-  combine count dominates and work-efficiency pays; a parallel-sequence
-  schedule (decoupled) when a long row would otherwise serialize —
-  ``batch < cores`` AND the row spans multiple blocks AND there are at
-  least ``cores // batch`` chunks to spread. Serve-engine decode and SSM
-  prefill (B=1, N ≥ 2^22) land on decoupled; training shapes (B ≥ 8)
-  keep the carry chain at default blocks.
+  machine; cheapest traffic); a parallel-sequence schedule (decoupled)
+  when a long row would otherwise serialize — ``batch < cores`` AND the
+  row spans multiple blocks AND there are at least ``cores // batch``
+  chunks to spread. Serve-engine decode and SSM prefill (B=1, N ≥ 2^22)
+  land on decoupled; training shapes (B ≥ 8) keep the carry chain.
 """
 
 from __future__ import annotations
@@ -76,16 +74,6 @@ L2_HALF_FLOATS = 128 * 1024  # the paper's best CPU partition: ½ L2 in elems
 # class; 8 also matches the paper's CPU thread sweet spot (Fig. 7).
 NUM_CORES = 8
 
-# In-tile element count above which the work-efficient tree network pays
-# for its strided deinterleave/interleave passes (the paper's Observation
-# 5 tradeoff): the Hillis–Steele network does O(b log b) combines per
-# tile vs the tree's O(b), so the tree's advantage grows with the block
-# length, while its reshuffle overhead is roughly flat per level. Below
-# this the lane-parallel log network wins; at the default 2048-element
-# blocks the carry schedule keeps the job.
-TREE_BLOCK_ELEMS = 8192
-
-
 @dataclasses.dataclass(frozen=True)
 class Choice:
     algorithm: str  # 'horizontal' | 'blocked' | 'two_pass' | 'kernel'
@@ -93,7 +81,7 @@ class Choice:
     variant: int  # two-pass organization (1 = scan-first, 2 = reduce-first)
     carry_exchange: str  # distributed sums exchange
     reason: str
-    schedule: str = "carry"  # grid org: 'carry'|'decoupled'|'tree'
+    schedule: str = "carry"  # grid org: 'carry'|'decoupled'
     # The inputs the choice was made from (the explain surface) — filled
     # by ``choose``; excluded from equality so cached/reconstructed
     # Choices with the same outcome still compare equal.
@@ -133,14 +121,6 @@ def explain_schedule(
     inputs = dict(batch=batch, n=n, cores=cores, block_elems=block_elems,
                   chunks=chunks, spare=spare)
     if batch >= cores:
-        if block_elems >= TREE_BLOCK_ELEMS:
-            return Decision(
-                "schedule", "tree",
-                f"batch {batch} >= cores {cores} and block_elems "
-                f"{block_elems} >= {TREE_BLOCK_ELEMS}: rows fill every "
-                f"core and the tile is long enough that the "
-                f"work-efficient tree sweep beats the log network",
-                inputs).emit()
         return Decision(
             "schedule", "carry",
             f"batch {batch} >= cores {cores}: rows alone fill every core; "

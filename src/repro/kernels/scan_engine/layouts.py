@@ -121,11 +121,12 @@ class Rows(_UniformLeaves):
 
     # Chunk totals are kept as (row blocks, chunks, bb, LANES): every
     # grid cell's (bb, 1) carry column, replicated across one lane
-    # tile, is a whole trailing (sublane, lane) plane of its own — the
-    # one block shape the TPU compiler accepts for any bb and any chunk
-    # count, and one a DMA may slice. A (bb, 1) column pads to a full
-    # lane tile in HBM and VMEM anyway, so the replication costs no
-    # memory.
+    # tile, is a whole trailing (sublane, lane) plane of its own — a
+    # block shape the TPU compiler accepts for any bb and any chunk
+    # count, and one a DMA may slice. The price is 128x the bytes of a
+    # lane-dense (rows, chunks) chain: compiled for a v5e, the totals
+    # and the offsets of a 2^28-element cumsum (131072 chunks, bb=1)
+    # are 64 MiB each, where a lane-dense chain would be 512 KiB.
     def chain_spec(self):
         return pl.BlockSpec(self.chain_block, lambda i, j: (i, j, 0, 0))
 

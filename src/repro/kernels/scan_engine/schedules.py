@@ -20,15 +20,16 @@ segmented, affine-SSM and compact-mask all run the SAME bodies:
              read n + write n with decoupled's parallelism. Runs only
              compiled on a TPU, on grids that fit the chip's semaphore
              memory; elsewhere it raises. The policy never picks it.
-  tree       the paper's work-efficient balanced tree (§3.3, Observation
-             5; Blelloch's up-sweep/down-sweep): the carry schedule's
-             grid and inter-block carry, but the IN-TILE network replaced
-             by Blelloch's pairwise up-sweep (each right child combined
+  tree       the paper's balanced tree (§3.3, Observation 5; Blelloch's
+             up-sweep/down-sweep): the carry schedule's grid and
+             inter-block carry, but the IN-TILE network replaced by
+             Blelloch's pairwise up-sweep (each right child combined
              with its left sibling, doubling the stride) and down-sweep
              (parent prefixes fan back out, ``combine(parent,
-             old_left)``), written in place as masked strided passes —
-             the access pattern the paper's Observation 5 charges it
-             with, confined to VMEM. HBM: read n + write n.
+             old_left)``), written in place as full-width masked passes
+             — 2·log2(b) of them per b-element tile, so not
+             work-efficient as written: about twice the log network's
+             combines. The policy never picks it. HBM: read n + write n.
 
 Bit-identity across schedules holds by construction for every monoid:
 carry/decoupled/fused run the identical in-tile scan network, and the
@@ -174,7 +175,7 @@ def _pad_to(x, m, axis, fill):
 
 
 def tree_scan(spec: KernelSpec, leaves, axis):
-    """Work-efficient in-tile EXCLUSIVE scan (§3.3 balanced tree).
+    """In-tile EXCLUSIVE scan in the §3.3 balanced tree's association.
 
     Blelloch's up-sweep/down-sweep written in place over the whole tile,
     so the compiler sees only shifts, an iota and selects — no reshape
@@ -186,7 +187,9 @@ def tree_scan(spec: KernelSpec, leaves, axis):
     prefix and each right child ``combine(parent, old_left)``, the
     convention the ``core.scan.tree`` oracle pins. The tree's
     association is the recursive pairwise one; the masked passes are
-    full-width, log2(n) of them each way, all inside VMEM.
+    full-width, log2(n) of them each way, all inside VMEM — each a
+    combine plus selects over the whole tile, so the sweep does
+    O(n log n) work, not the O(n) of a compacting tree.
 
     Pads the scan axis to a power of two with the monoid identity (the
     padded tail contributes identity to every prefix and to the root
@@ -420,8 +423,10 @@ def scan_decoupled(operands, spec, layout, *, exclusive=False,
 
 
 # The native chain holds one regular semaphore per grid cell, and the
-# chip's semaphore memory is small: compiled against a described v5e, a
-# 384-cell grid fits its 2 KiB and a 448-cell grid is refused.
+# chip's semaphore memory is small. This is the TPU v5e figure: compiled
+# against a described v5e, a 384-cell grid fits its 2 KiB and a 448-cell
+# grid is refused. Another TPU generation needs its own compile check
+# (and, if it differs, a cap keyed by ``device_kind``).
 FUSED_MAX_CELLS = 256
 
 
@@ -528,7 +533,7 @@ def scan_fused(operands, spec, layout, *, exclusive=False, interpret=False,
 
 
 # ---------------------------------------------------------------------------
-# Schedule 4: tree (work-efficient Blelloch sweep inside each tile)
+# Schedule 4: tree (Blelloch sweep inside each tile)
 # ---------------------------------------------------------------------------
 
 

@@ -248,11 +248,11 @@ def test_segmented_messy_flags_match_reference():
 
 
 def test_fused_falls_back_to_decoupled():
-    """It does not, any more: the fused schedule's semaphore chain runs
-    only compiled on a TPU, on grids that fit the chip's semaphore
-    memory. Anywhere else a fused request raises — it never runs
-    decoupled under fused's name — and folds, which have no fused form,
-    refuse it too."""
+    """Fused never falls back to decoupled. Its semaphore chain runs only
+    compiled on a TPU, on grids that fit the chip's semaphore memory;
+    anywhere else a fused request raises rather than running decoupled
+    under fused's name, and folds, which have no fused form, refuse it
+    too."""
     from repro.kernels.scan_engine import schedules
     x = jnp.asarray(
         np.random.default_rng(4).standard_normal((1, 8192)), jnp.float32)
@@ -371,29 +371,19 @@ def test_choose_schedule_single_block_rows():
 
 
 def test_choose_schedule_tree_boundary():
-    """Tree fires only when rows saturate the cores AND the block is big
-    enough to amortize the sweep; the default block (2048) never trips
-    it, so every pre-tree auto decision is unchanged."""
+    """The policy never answers tree: its in-tile network does about
+    twice the log network's work on the chip, and no cell has shown it
+    winning. Saturated rows keep carry and under-subscribed rows spread
+    with decoupled, whatever the block size."""
     n = 1 << 22
     cores = policy.NUM_CORES
-    # saturated rows + big block -> tree
-    assert policy.choose_schedule(cores, n,
-                                  block_elems=policy.TREE_BLOCK_ELEMS) \
-        == "tree"
-    assert policy.choose_schedule(cores * 4, n, block_elems=16384) == "tree"
-    # one element under the threshold -> carry (the old answer)
-    assert policy.choose_schedule(cores, n,
-                                  block_elems=policy.TREE_BLOCK_ELEMS - 1) \
-        == "carry"
-    # default block: unchanged decisions
-    assert policy.choose_schedule(cores, n) == "carry"
-    # under-subscribed rows never pick tree, whatever the block size
-    assert policy.choose_schedule(cores // 2, n,
-                                  block_elems=policy.TREE_BLOCK_ELEMS) \
-        == "decoupled"
-    d = policy.explain_schedule(cores, n,
-                                block_elems=policy.TREE_BLOCK_ELEMS)
-    assert d.value == "tree" and "block_elems" in d.reason
+    for block in (2048, 8192, 16384, 1 << 16):
+        assert policy.choose_schedule(cores, n, block_elems=block) == "carry"
+        assert policy.choose_schedule(cores * 4, n,
+                                      block_elems=block) == "carry"
+        assert policy.choose_schedule(cores // 2, n,
+                                      block_elems=block) == "decoupled"
+    assert not hasattr(policy, "TREE_BLOCK_ELEMS")
 
 
 @pytest.mark.parametrize("itemsize", [1, 2, 4, 8])

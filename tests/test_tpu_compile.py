@@ -58,11 +58,11 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, sharding, *shapes):
+def _compile(fn, sharding, *shapes, kernel=True):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
             for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert ("tpu_custom_call" in compiled.as_text()) == kernel
     return compiled
 
 
@@ -163,18 +163,17 @@ def test_flash_backward_compiles(one_chip, schedule):
 
 @pytest.mark.parametrize("exchange", ["all_gather", "hillis_permute",
                                       "ring"])
-def test_sharded_scan_compiles_for_four_chips(topo, exchange,
-                                              monkeypatch):
-    """``chip_smoke.py --chips 4``: 2^30 int32 over a 4-chip mesh, the
-    engine kernel as each chip's local scan (which picks compiled mode
-    from the backend it sees — steered to the chip's answer here)."""
-    monkeypatch.setattr(sb_ops, "_on_tpu", lambda: True)
+def test_sharded_scan_compiles_for_four_chips(topo, exchange):
+    """``chip_smoke.py --chips 4``: 2^30 int32 over a 4-chip mesh through
+    ``scan_sharded``'s default per-chip scan (the blocked jnp scan; no
+    Pallas kernel), each chip holding its own quarter."""
     mesh = make_mesh((4,), ("d",), devices=topo.devices)
     sh = NamedSharding(mesh, P("d"))
     compiled = _compile(
         lambda v: scanlib.scan_sharded(
             v, "sum", mesh=mesh, axis_name="d", spec=P("d"),
-            carry_exchange=exchange, local_algorithm="kernel"),
-        sh, ((1 << 30,), I32))
-    per_device = compiled.memory_analysis().argument_size_in_bytes
-    assert per_device == (1 << 30) * 4 // 4
+            carry_exchange=exchange),
+        sh, ((1 << 30,), I32), kernel=False)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == (1 << 30) * 4 // 4
+    assert mem.output_size_in_bytes == (1 << 30) * 4 // 4
