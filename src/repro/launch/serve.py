@@ -13,8 +13,9 @@ engine had to retry, roll back or degrade a step: on a healthy run none
 of that happens, and a recovery would hide a failing kernel.
 
 Observability knobs: ``--trace out.json`` captures the run as a Chrome
-``trace_event`` file (open in ui.perfetto.dev — tick/prefill/decode
-spans, request lifecycle instants, policy decisions); ``--stats-json``
+``trace_event`` file (open in ui.perfetto.dev — each tick's
+``serve.step`` span and its phase spans, request lifecycle instants,
+policy decisions); ``--stats-json``
 prints one machine-parsable line with the full ``EngineStats.as_dict()``
 plus the metrics-registry snapshot (tick-latency histogram included).
 """

@@ -22,9 +22,18 @@ Chrome ``trace_event`` JSON object format — load the file in
 The module-level singleton is DISABLED by default: ``span`` hands back a
 shared no-op context manager and ``instant``/``counter`` return before
 touching the clock, so instrumented hot paths (engine ticks, policy
-resolution inside a jit trace) pay one attribute check. ``enable()``
-swaps in a live ``Tracer``; library code uses the module-level functions
-and never holds a tracer reference across an enable/disable.
+resolution inside a jit trace) pay one attribute check and one query of
+the profiler. ``enable()`` swaps in a live ``Tracer``; library code uses
+the module-level functions and never holds a tracer reference across an
+enable/disable.
+
+Spans on the profiler's clock: while a ``jax.profiler`` session records
+(``jax.profiler.start_trace``, TensorBoard, ``bench/run.py --trace 1``),
+every ``span`` also opens a ``jax.profiler.TraceAnnotation`` of the same
+name, enabled tracer or not, so its event lands in the session's host
+plane beside the device's ops. Only the span's scalar args go with it
+(as event stats; the name stays exact). ``instant`` and ``counter`` stay
+in the ring buffer.
 
 Note on jitted callers: instrumentation that runs inside ``jax.jit``
 tracing (kernel-launch events, policy decisions reached from a jitted
@@ -40,6 +49,8 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 # Chrome trace_event phases we emit.
 _PH_COMPLETE = "X"
 _PH_INSTANT = "i"
@@ -50,8 +61,16 @@ def _now_us() -> float:
     return time.perf_counter_ns() / 1e3
 
 
+def _annotation(name: str, args: Dict[str, Any]) -> Optional[TraceAnnotation]:
+    """The profiler event for a span, or None when no session records."""
+    if not TraceAnnotation.is_enabled():
+        return None
+    return TraceAnnotation(name, **{
+        k: v for k, v in args.items() if isinstance(v, (int, float, str))})
+
+
 def _jsonable(v: Any) -> Any:
-    """Coerce event args to JSON-safe values without importing jax."""
+    """Coerce event args to JSON-safe values."""
     if isinstance(v, (str, int, float, bool)) or v is None:
         return v
     if isinstance(v, (list, tuple)):
@@ -77,20 +96,26 @@ _NOOP_SPAN = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_mark")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self._tracer = tracer
         self._name = name
         self._args = args
         self._t0 = 0.0
+        self._mark = None
 
     def __enter__(self):
+        self._mark = _annotation(self._name, self._args)
+        if self._mark is not None:
+            self._mark.__enter__()
         self._t0 = _now_us()
         return self
 
     def __exit__(self, *exc):
         t1 = _now_us()
+        if self._mark is not None:
+            self._mark.__exit__(*exc)
         self._tracer._record({
             "name": self._name, "ph": _PH_COMPLETE, "ts": self._t0,
             "dur": t1 - self._t0, "pid": 0,
@@ -116,7 +141,8 @@ class Tracer:
 
     def span(self, name: str, **args):
         if not self.enabled:
-            return _NOOP_SPAN
+            mark = _annotation(name, args)
+            return _NOOP_SPAN if mark is None else mark
         return _Span(self, name, args)
 
     def instant(self, name: str, **args) -> None:

@@ -977,7 +977,7 @@ class Engine:
         the tick operated on. Bookkeeping commits only on success — a
         failed or non-finite tick leaves the pool exactly as it was."""
         t0 = time.perf_counter()
-        with trace.span("serve.tick", tick=self._tick + 1):
+        with trace.span("serve.step", tick=self._tick + 1):
             n = self._step_inner()
         if self.metrics is not None:
             self.metrics.histogram("serve.tick_s").record(
@@ -989,21 +989,28 @@ class Engine:
         t0 = time.perf_counter()
         self._tick += 1
         self.stats.ticks += 1
-        self._expire_deadlines()
-        self._maybe_defrag()
-        self._admit()
-        self._ensure_pages()
-        active = self._active()
+        # Phase spans: together they cover the tick, so a profiler
+        # session can put each stretch of device idle time down to one.
+        with trace.span("serve.admit", tick=self._tick):
+            self._expire_deadlines()
+            self._maybe_defrag()
+            self._admit()
+            self._ensure_pages()
+            active = self._active()
         if not active:
             return 0
-        result = self._robust_step(active)
+        with trace.span("serve.decode", tick=self._tick,
+                        rids=[self.slot_req[i].rid for i in active]):
+            result = self._robust_step(active)
         if result is None:                     # pool emptied by quarantine
             return 0
         logits, new_cache, active = result
 
         # -- numeric degradation ladder --------------------------------
-        logits_np = np.asarray(logits)
-        if not np.isfinite(logits_np[active]).all():
+        with trace.span("serve.sync", tick=self._tick):
+            logits_np = np.asarray(logits)
+            finite = np.isfinite(logits_np[active]).all()
+        if not finite:
             self.stats.nonfinite_ticks += 1
             handled = False
             if self.ecfg.degrade_on_nonfinite and not self._pre_cache_gone():
@@ -1027,29 +1034,30 @@ class Engine:
 
         # -- commit ----------------------------------------------------
         self.cache = new_cache
-        nxt = self._sample(logits)
-        nxt_np = np.asarray(nxt)
-        new_tokens = self.tokens
-        for i in active:
-            req = self.slot_req[i]
-            tok = int(nxt_np[i])
-            req.output.append(tok)
-            self.stats.tokens_generated += 1
-            self.lengths[i] += 1
-            self.budgets[i] -= 1
-            if tok == self.ecfg.eos_id:
-                reason = "eos"
-            elif self.budgets[i] <= 0:
-                reason = "length_budget"
-            elif self.lengths[i] + 1 >= self.ecfg.max_len:
-                reason = "cache_full"
-                self._warn_cache_full(req)
-            else:
-                new_tokens = new_tokens.at[i, 0].set(tok)
-                continue
-            self._release(i)
-            self._finish(req, reason)
-        self.tokens = new_tokens
+        with trace.span("serve.sample", tick=self._tick):
+            nxt_np = np.asarray(self._sample(logits))
+        with trace.span("serve.commit", tick=self._tick):
+            new_tokens = self.tokens
+            for i in active:
+                req = self.slot_req[i]
+                tok = int(nxt_np[i])
+                req.output.append(tok)
+                self.stats.tokens_generated += 1
+                self.lengths[i] += 1
+                self.budgets[i] -= 1
+                if tok == self.ecfg.eos_id:
+                    reason = "eos"
+                elif self.budgets[i] <= 0:
+                    reason = "length_budget"
+                elif self.lengths[i] + 1 >= self.ecfg.max_len:
+                    reason = "cache_full"
+                    self._warn_cache_full(req)
+                else:
+                    new_tokens = new_tokens.at[i, 0].set(tok)
+                    continue
+                self._release(i)
+                self._finish(req, reason)
+            self.tokens = new_tokens
         if (self.ecfg.slow_tick_s is not None
                 and time.perf_counter() - t0 > self.ecfg.slow_tick_s):
             self.stats.slow_ticks += 1
@@ -1118,11 +1126,8 @@ class Engine:
             if self.injector is not None:
                 self.injector.begin(self._ctx(active))
             try:
-                with trace.span("serve.decode", tick=self._tick,
-                                rids=[self.slot_req[i].rid for i in active],
-                                attempt=attempts):
-                    logits, new_cache = self._wstep(
-                        self.params, self.tokens, self.cache, clv)
+                logits, new_cache = self._wstep(
+                    self.params, self.tokens, self.cache, clv)
                 return logits, new_cache, active
             except Exception as e:            # noqa: BLE001 — jitted call
                 last_err = e

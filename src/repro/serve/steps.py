@@ -21,7 +21,6 @@ from repro.dist import sharding as shd
 from repro.models import encdec as encdec_mod
 from repro.models import lm as lm_mod
 from repro.models.config import ModelConfig
-from repro.obs import trace
 
 Pytree = Any
 
@@ -71,13 +70,6 @@ def make_prefill_fn(cfg: ModelConfig, max_len: int, unroll: bool = False,
         return fn
 
     def fn(params, tokens, embeds=None):
-        # Fires once per jit COMPILATION (this fn is traced, not run, by
-        # the engine's jit) — one event per prefill variant, mirroring
-        # the prefill_compiles counter.
-        trace.instant("serve.prefill.variant", batch=tokens.shape[0],
-                      prompt_len=tokens.shape[1], bucketed=False,
-                      attn_impl=attn_impl or "dense",
-                      attn_schedule=attn_schedule)
         logits, cache = lm_mod.prefill(
             params, tokens, cfg, max_len, embeds=embeds,
             attn_impl=attn_impl, attn_schedule=attn_schedule,
@@ -130,12 +122,7 @@ def make_bucketed_prefill_fn(cfg: ModelConfig, max_len: int,
             f"got pattern {cfg.layer_pattern!r}")
 
     def fn(params, tokens, true_len):
-        B, S = tokens.shape
-        # Once per compiled bucket variant (see make_prefill_fn).
-        trace.instant("serve.prefill.variant", batch=B, bucket=S,
-                      bucketed=True, attn_impl=attn_impl or "dense",
-                      attn_schedule=attn_schedule)
-        cache = lm_mod.init_cache(cfg, B, max_len)
+        cache = lm_mod.init_cache(cfg, tokens.shape[0], max_len)
         hidden, _, cache = lm_mod.forward(
             params, tokens, cfg, cache=cache,
             cache_len=jnp.zeros((), jnp.int32), attn_impl=attn_impl,
@@ -245,12 +232,6 @@ def make_chunked_prefill_fn(cfg: ModelConfig, max_len: int,
             f"got pattern {cfg.layer_pattern!r}")
 
     def fn(params, tokens, cache, cache_len, true_len):
-        B, C = tokens.shape
-        # Once per compiled chunk variant (see make_prefill_fn).
-        trace.instant("serve.prefill.variant", batch=B, chunk=C,
-                      bucketed=False, chunked=True,
-                      attn_impl=attn_impl or "dense",
-                      attn_schedule=attn_schedule)
         hidden, _, cache = lm_mod.forward(
             params, tokens, cfg, cache=cache, cache_len=cache_len,
             attn_impl=attn_impl, attn_schedule=attn_schedule,

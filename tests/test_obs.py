@@ -19,6 +19,7 @@ The contract under test:
   * the scan engine emits a ``kernel.launch`` event per compilation.
 """
 
+import collections
 import copy
 import dataclasses
 import glob
@@ -168,6 +169,89 @@ def test_serve_outputs_bitwise_identical_with_tracing(small_model):
     finally:
         trace.disable()
     assert traced == base  # token-for-token identical histories
+
+
+def _host_spans(trace_dir, prefix):
+    """``name -> [(start_ns, end_ns)]`` of the host events of the one
+    ``.xplane.pb`` under ``trace_dir`` whose names start with ``prefix``,
+    and each event's stats."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    spans, stats = collections.defaultdict(list), {}
+    with warnings.catch_warnings():
+        # jaxlib's stats type warns when first built
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith(prefix):
+                        spans[e.name].append(
+                            (e.start_ns, e.start_ns + e.duration_ns))
+                        stats[e.name] = dict(e.stats)
+    return spans, stats
+
+
+def test_spans_reach_a_profiler_session_with_scalar_args(tracer, tmp_path):
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace.span("probe.outer", tick=3, rids=[1, 2], label="x"):
+            with trace.span("probe.inner"):
+                pass
+        trace.instant("probe.instant")
+    finally:
+        jax.profiler.stop_trace()
+    spans, stats = _host_spans(str(tmp_path), "probe.")
+    # exact names; the list arg stays in the ring buffer only
+    assert set(spans) == {"probe.outer", "probe.inner"}
+    assert stats["probe.outer"] == {"tick": 3, "label": "x"}
+    (a, b), = spans["probe.outer"]
+    (c, d), = spans["probe.inner"]
+    assert a <= c <= d <= b
+    ring = {e["name"]: e for e in tracer.events()}
+    assert set(ring) == {"probe.outer", "probe.inner", "probe.instant"}
+    assert ring["probe.outer"]["args"]["rids"] == [1, 2]
+
+
+def _two_ticks(cfg, params):
+    rng = np.random.default_rng(3)
+    eng = Engine(params, cfg, EngineConfig(
+        max_slots=2, max_len=48, max_new_tokens=5, eos_id=-1,
+        temperature=0.0))
+    reqs = [Request(rid=rid, prompt=rng.integers(2, 500, size=6)
+                    .astype(np.int32)) for rid in range(2)]
+    for r in reqs:
+        eng.submit(r)
+    eng.step()
+    eng.step()
+    return [list(r.output) for r in reqs]
+
+
+def test_profiler_session_captures_the_tick_phases(small_model, tmp_path):
+    """With the ring tracer off, a ``jax.profiler`` session alone records
+    every phase span of a tick, inside its ``serve.step``."""
+    cfg, params = small_model
+    trace.disable()
+    base = _two_ticks(cfg, params)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        traced = _two_ticks(cfg, params)
+    finally:
+        jax.profiler.stop_trace()
+    assert traced == base  # bitwise identical token histories
+    spans, _ = _host_spans(str(tmp_path), "serve.")
+    steps = sorted(spans["serve.step"])
+    assert len(steps) == 2 and "serve.tick" not in spans
+    for name in ("serve.admit", "serve.decode", "serve.sync",
+                 "serve.sample", "serve.commit"):
+        assert len(spans[name]) == 2, name
+        for (a, b), (s0, s1) in zip(sorted(spans[name]), steps):
+            assert s0 <= a <= b <= s1, name
+    assert spans["serve.prefill"]
+    for a, b in spans["serve.prefill"]:
+        assert any(s0 <= a <= b <= s1 for s0, s1 in spans["serve.admit"])
 
 
 # ---------------------------------------------------------------------------

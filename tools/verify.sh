@@ -229,7 +229,7 @@ python - <<'EOF'
 import json
 doc = json.load(open("/tmp/serve_trace.json"))
 evs = doc["traceEvents"]
-assert any(e["name"] == "serve.tick" and e["ph"] == "X" for e in evs)
+assert any(e["name"] == "serve.step" and e["ph"] == "X" for e in evs)
 assert any(e["name"] == "serve.request.finish" for e in evs)
 assert any(e["name"].startswith("policy.") for e in evs)
 line = [l for l in open("/tmp/serve_out.txt")
