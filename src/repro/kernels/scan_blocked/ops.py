@@ -1,9 +1,13 @@
 """Blocked prefix sum: the SUM registration of the Pallas scan engine.
 
 This family is nothing but the sum monoid run through the monoid-generic
-engine (``repro.kernels.scan_engine``) on the Rows layout — the hand
-rolled carry/decoupled kernel bodies that used to live here are the
-engine's schedules now, written once for every monoid.
+engine (``repro.kernels.scan_engine``) — the hand rolled carry/decoupled
+kernel bodies that used to live here are the engine's schedules now,
+written once for every monoid. The layout follows from the shape: fewer
+rows than a vreg's eight sublanes (one long column above all) run on
+Flat, whole (8, 128) vregs of one row per tile, under the carry and
+decoupled schedules; eight rows or more, and an explicit ``tree`` or
+``fused``, run on Rows.
 
 The public wrapper handles arbitrary ranks/axes, padding to block
 multiples, dtype policy and interpret-mode fallback on CPU.
@@ -33,11 +37,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.scan import assoc, policy
 from repro.kernels import scan_engine
 from repro.kernels.scan_engine import monoids
+from repro.kernels.scan_engine.layouts import SUBLANES, VREG
 from repro.kernels.scan_engine import resolve_schedule  # back-compat export
 
 SCHEDULES = scan_engine.RESOLVABLE
+ROWS_BLOCK_N = 2048  # the Rows chunk length when the caller names none
 
 
 def _on_tpu() -> bool:
@@ -58,22 +65,50 @@ def _cumsum_impl(x, axis, exclusive, block_b, block_n, interpret, schedule):
         b *= d
     x2 = x.reshape(b, n)
 
-    bb = min(block_b, b) if b % min(block_b, b) == 0 else 1
-    pad_b = (-b) % bb
-    bn = min(block_n, _round_up(n, 128))
-    pad_n = (-n) % bn
-    x2 = jnp.pad(x2, ((0, pad_b), (0, pad_n)))
-
-    layout = scan_engine.Rows(x2.shape[0], x2.shape[1], bb, bn)
-    out, = scan_engine.scan(
-        (x2,), monoids.SUM, layout, schedule=schedule, exclusive=exclusive,
-        interpret=interpret)
+    if _flat(b, schedule):
+        bn = _flat_block(n, x.dtype, block_n)
+        x2 = jnp.pad(x2, ((0, 0), (0, (-n) % bn)))
+        layout = scan_engine.Flat(b, x2.shape[1], bn)
+        out, = scan_engine.scan(
+            (x2.reshape(layout.shape),), monoids.SUM, layout,
+            schedule=schedule, exclusive=exclusive, interpret=interpret)
+        out = out.reshape(b, -1)
+    else:
+        block_n = ROWS_BLOCK_N if block_n is None else block_n
+        bb = min(block_b, b) if b % min(block_b, b) == 0 else 1
+        pad_b = (-b) % bb
+        bn = min(block_n, _round_up(n, 128))
+        pad_n = (-n) % bn
+        x2 = jnp.pad(x2, ((0, pad_b), (0, pad_n)))
+        layout = scan_engine.Rows(x2.shape[0], x2.shape[1], bb, bn)
+        out, = scan_engine.scan(
+            (x2,), monoids.SUM, layout, schedule=schedule,
+            exclusive=exclusive, interpret=interpret)
     out = out[:b, :n].reshape(lead + (n,))
     return jnp.moveaxis(out, -1, axis)
 
 
 def _round_up(v: int, m: int) -> int:
     return -(-v // m) * m
+
+
+def _flat(b: int, schedule: str) -> bool:
+    """Fewer rows than a vreg has sublanes run on the Flat layout under
+    the schedules the policy picks (``auto`` resolves to one of them);
+    the rest, and an explicit ``tree`` or ``fused``, on Rows."""
+    return b < SUBLANES and schedule in ("carry", "decoupled", "auto")
+
+
+def _flat_block(n: int, dtype, block_n: "int | None") -> int:
+    """The Flat chunk length: ``block_n`` rounded up to whole vregs if
+    the caller names one; else an eighth of the policy's VMEM block
+    budget, so the tile's input and output, double-buffered, take half
+    of it and the network's temporaries the rest (2^18 32-bit elements,
+    1 MiB). Never longer than the row, rounded up to whole vregs."""
+    if block_n is None:
+        block_n = policy.VMEM_BLOCK_BUDGET // (
+            8 * assoc.accum_dtype(dtype).itemsize)
+    return min(_round_up(block_n, VREG), _round_up(n, VREG))
 
 
 # Gradient-as-a-scan: d(prefix sum)/dx is a SUFFIX sum of the cotangent
@@ -110,7 +145,7 @@ def cumsum(
     axis: int = -1,
     exclusive: bool = False,
     block_b: int = 8,
-    block_n: int = 2048,
+    block_n: "int | None" = None,
     interpret: "bool | None" = None,
     schedule: str = "auto",
 ) -> jax.Array:
@@ -118,8 +153,10 @@ def cumsum(
 
     ``interpret=None`` auto-selects: compiled on TPU, interpret elsewhere.
     ``schedule`` picks the grid organization
-    (carry|decoupled|fused|tree|auto). Differentiable: the custom VJP
-    runs the backward as another engine scan (see module doc).
+    (carry|decoupled|fused|tree|auto). ``block_n`` is the chunk length;
+    ``None`` lets the layout choose (2048 on Rows; on Flat, see
+    ``_flat_block``). Differentiable: the custom VJP runs the backward
+    as another engine scan (see module doc).
     """
     if interpret is None:
         interpret = not _on_tpu()
@@ -130,7 +167,11 @@ def cumsum(
         return x
     n = x.shape[axis]
     batch = max(x.size // max(n, 1), 1)
-    bn = min(block_n, _round_up(n, 128))  # the block _cumsum_impl uses
+    if _flat(batch, schedule):   # the chunk _cumsum_impl will tile with
+        bn = _flat_block(n, x.dtype, block_n)
+    else:
+        bn = min(ROWS_BLOCK_N if block_n is None else block_n,
+                 _round_up(n, 128))
     schedule = resolve_schedule(schedule, batch, n, bn)
     return _cumsum_vjp(x, axis, exclusive, block_b, block_n, interpret,
                        schedule)

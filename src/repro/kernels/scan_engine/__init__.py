@@ -37,8 +37,9 @@ extra intermediate traffic loses under Observation 3, so the engine does
 not ship it as a schedule — ``core.scan.blocked.scan_two_pass`` keeps it
 available as a library oracle.)
 
-Geometry lives in ``layouts`` (Rows for 2D batch×sequence, Channels for
-SSM batch×time×channel tiles, KVBlocks for the attention fold);
+Geometry lives in ``layouts`` (Rows for 2D batch×sequence, Flat for
+fewer than eight long rows, Channels for SSM batch×time×channel tiles,
+KVBlocks for the attention fold), in-tile networks in ``network``;
 ``core/scan/policy.choose_schedule`` arbitrates the three-way schedule
 choice (``choose_attention_schedule`` the two-way fold variant). The
 five kernel families under
@@ -48,19 +49,19 @@ are thin back-compat wrappers over this engine — adding a new schedule
 """
 
 from repro.kernels.scan_engine import monoids
-from repro.kernels.scan_engine.layouts import (Channels, KVBlocks, QBlocks,
-                                               Rows, block_live)
+from repro.kernels.scan_engine.layouts import (Channels, Flat, KVBlocks,
+                                               QBlocks, Rows, block_live)
+from repro.kernels.scan_engine.network import tile_scan, tree_scan
 from repro.kernels.scan_engine.schedules import (RESOLVABLE, SCHEDULES,
                                                  exclusive_chain, fold_carry,
                                                  fold_chain, fold_decoupled,
                                                  resolve_schedule, scan,
                                                  scan_carry, scan_decoupled,
-                                                 scan_fused, scan_tree,
-                                                 tile_scan, tree_scan)
+                                                 scan_fused, scan_tree)
 
 __all__ = [
-    "Channels", "KVBlocks", "QBlocks", "RESOLVABLE", "Rows", "SCHEDULES",
-    "block_live", "exclusive_chain", "fold_carry", "fold_chain",
+    "Channels", "Flat", "KVBlocks", "QBlocks", "RESOLVABLE", "Rows",
+    "SCHEDULES", "block_live", "exclusive_chain", "fold_carry", "fold_chain",
     "fold_decoupled", "monoids",
     "resolve_schedule", "scan", "scan_carry", "scan_decoupled",
     "scan_fused", "scan_tree", "tile_scan", "tree_scan",

@@ -1,13 +1,20 @@
 """Grid/tile layouts the monoid-generic schedules are written against.
 
 A layout answers every SHAPE question a schedule has — grid geometry,
-block specs, carry/chunk-total shapes, how to read a tile out of a ref —
-so the schedule bodies in ``schedules.py`` contain no per-family
-geometry. Three layouts cover the five kernel families:
+block specs, carry/chunk-total shapes, how to read a tile out of a ref,
+which in-tile network (``network.py``) scans it — so the schedule
+bodies in ``schedules.py`` contain no per-family geometry. Five layouts
+cover the five kernel families:
 
   Rows      (R, N) leaves scanned along the last axis in (bb, bn) VMEM
             tiles; rows are the paper's threads. Used by the sum,
             segmented and compact-mask registrations.
+  Flat      fewer than eight (R, N) leaves — one long column is the
+            common case — each viewed as an (N/128, 128) plane and
+            scanned in lane-dense (bn/1024, 8, 128) tiles of whole
+            vregs, one chunk total per tile. Used by the sum
+            registration (``scan_blocked.cumsum``) for the carry and
+            decoupled schedules.
   Channels  (B, T, D) leaves scanned along the TIME axis in (1, bt, bd)
             tiles; channels ride the 128-lane axis as independent lanes
             (the paper's §3.2 vertical SIMD — natural on TPU, not a
@@ -46,7 +53,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-LANES = 128
+from repro.kernels.scan_engine import network
+from repro.kernels.scan_engine.network import LANES, SUBLANES
+
+VREG = SUBLANES * LANES   # 32-bit elements in one vreg
 
 
 def _check_divisible(shape, block, what):
@@ -79,6 +89,13 @@ class _UniformLeaves:
 
     def chain_shape_for(self, leaf):
         return self.chain_shape
+
+    # -- in-tile network ------------------------------------------------
+    def scan_tile(self, spec, leaves):
+        return network.tile_scan(spec, leaves, self.scan_axis)
+
+    def shift_one(self, spec, leaves):
+        return network.shift_one(spec, leaves, self.scan_axis)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -124,9 +141,10 @@ class Rows(_UniformLeaves):
     # tile, is a whole trailing (sublane, lane) plane of its own — a
     # block shape the TPU compiler accepts for any bb and any chunk
     # count, and one a DMA may slice. The price is 128x the bytes of a
-    # lane-dense (rows, chunks) chain: compiled for a v5e, the totals
-    # and the offsets of a 2^28-element cumsum (131072 chunks, bb=1)
-    # are 64 MiB each, where a lane-dense chain would be 512 KiB.
+    # lane-dense (rows, chunks) chain, paid per (bb, bn) chunk: at
+    # bb=1, bn=2048 a 2^28-element row would hold 64 MiB of totals and
+    # 64 MiB of offsets. Rows of fewer than eight therefore run on Flat,
+    # whose chunks are a hundred times longer.
     def chain_spec(self):
         return pl.BlockSpec(self.chain_block, lambda i, j: (i, j, 0, 0))
 
@@ -177,6 +195,100 @@ class Rows(_UniformLeaves):
 
     def sem_at(self, sem, seq_index):
         return sem.at[pl.program_id(0), seq_index]
+
+
+@dataclasses.dataclass(frozen=True)
+class Flat(_UniformLeaves):
+    """(rows, n) leaves, fewer rows than a vreg has sublanes, each row
+    scanned as an (n/128, 128) plane in blocks of ``bn`` elements.
+
+    Operands and outputs have the shape ``(rows, n/1024, 8, 128)``, the
+    bytes of the (rows, n) array exactly (a row-major (8, 128) tile is
+    1,024 consecutive elements), so a column reaches the kernel through
+    a bitcast. A block is ``(1, bn/1024, 8, 128)``: whole vregs of one
+    row, every sublane used, where a (1, bn) Rows block fills one
+    sublane in eight. The in-tile network is ``network.flat_scan``;
+    carries are (1, LANES) rows holding one value in every lane.
+
+    Chunk totals are ``(rows, chunks, 1, LANES)``: one (1, LANES) plane
+    per chunk, a block shape the compiler accepts, 512 bytes a chunk.
+    """
+
+    rows: int
+    n: int
+    bn: int
+
+    def __post_init__(self):
+        _check_divisible((self.n, self.bn), (self.bn, VREG), "Flat")
+
+    @property
+    def shape(self):
+        return (self.rows, self.n // VREG, SUBLANES, LANES)
+
+    @property
+    def grid(self):
+        return (self.rows, self.n // self.bn)
+
+    @property
+    def num_seq_blocks(self):
+        return self.n // self.bn
+
+    @property
+    def seq_grid_axis(self):
+        return len(self.grid) - 1
+
+    def semantics(self, seq_kind: str):
+        return ("parallel", seq_kind)
+
+    def data_spec(self):
+        return pl.BlockSpec((1, self.bn // VREG, SUBLANES, LANES),
+                            lambda i, j: (i, j, 0, 0))
+
+    def chain_spec(self):
+        return pl.BlockSpec(self.chain_block, lambda i, j: (i, j, 0, 0))
+
+    @property
+    def chain_shape(self):
+        return (self.rows, self.num_seq_blocks, 1, LANES)
+
+    @property
+    def chain_block(self):
+        return (1, 1, 1, LANES)
+
+    def unchain(self, x):
+        """Chain array -> (rows, chunks)."""
+        return x[:, :, 0, 0]
+
+    def carry_scratch(self, dtype, leaf=0):
+        return pltpu.VMEM((1, LANES), dtype)
+
+    def read(self, ref):
+        return ref[0]  # (bn/1024, 8, 128)
+
+    def write(self, ref, val):
+        ref[0] = val.astype(ref.dtype)
+
+    def read_carry(self, ref):
+        return ref[...]
+
+    def write_carry(self, ref, val):
+        ref[...] = val.astype(ref.dtype)
+
+    def read_chain(self, ref):
+        return ref[0, 0]
+
+    def write_chain(self, ref, val):
+        ref[0, 0] = jnp.broadcast_to(val, (1, LANES)).astype(ref.dtype)
+
+    def take_last(self, x):
+        last = x[x.shape[0] - 1:, SUBLANES - 1:, LANES - 1:]
+        return jnp.broadcast_to(last.reshape(1, 1), (1, LANES))
+
+    def scan_tile(self, spec, leaves):
+        return network.flat_scan(spec, leaves)
+
+    def shift_one(self, spec, leaves):
+        return network.flat_shift_one(spec, leaves)
 
 
 @dataclasses.dataclass(frozen=True)

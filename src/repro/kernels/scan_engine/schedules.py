@@ -85,7 +85,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.scan import policy
 from repro.core.scan.assoc import KernelSpec
-from repro.kernels.scan_engine.layouts import LANES
+from repro.kernels.scan_engine.network import tree_scan
 from repro.obs import trace
 
 SCHEDULES = ("carry", "decoupled", "fused", "tree")
@@ -110,133 +110,8 @@ def resolve_schedule(schedule: str, batch: int, n: int,
 
 
 # ---------------------------------------------------------------------------
-# Monoid-generic in-tile scan network
+# Chunk-total chain
 # ---------------------------------------------------------------------------
-
-
-def _shift(x, k, axis, fill):
-    """Shift ``x`` by ``k`` along ``axis`` — right for ``k > 0``, left
-    for ``k < 0`` — filling the vacated slots with identity."""
-    n = x.shape[axis]
-    pad = [(0, 0)] * x.ndim
-    pad[axis] = (k, 0) if k > 0 else (0, -k)
-    sl = [slice(None)] * x.ndim
-    sl[axis] = slice(0, n) if k > 0 else slice(-k, n - k)
-    return jnp.pad(x, pad, constant_values=fill)[tuple(sl)]
-
-
-def shift_one(spec: KernelSpec, leaves, axis):
-    """Exclusive shift: one step right, identity-filled (all leaves)."""
-    return tuple(
-        _shift(x, 1, axis, f) for x, f in zip(leaves, spec.fills))
-
-
-def log_scan(spec: KernelSpec, leaves, axis):
-    """Hillis–Steele log-step inclusive scan of monoid leaves (§3.1)."""
-    n = leaves[0].shape[axis]
-    k = 1
-    while k < n:
-        shifted = tuple(
-            _shift(x, k, axis, f) for x, f in zip(leaves, spec.fills))
-        leaves = spec.combine(shifted, leaves)
-        k *= 2
-    return leaves
-
-
-def tile_scan(spec: KernelSpec, leaves, axis):
-    """In-tile inclusive scan; two-level lane/sublane split on lane axes.
-
-    When the scan axis is the (128-wide) lane axis and divisible, run the
-    paper's Fig. 3 scheme lifted to the monoid: scan within each lane row,
-    exclusive-scan the row totals along sublanes, broadcast-combine —
-    "scan the vector in register, broadcast the last element".
-    """
-    x0 = leaves[0]
-    n = x0.shape[axis]
-    last = x0.ndim - 1
-    if axis == last and n > LANES and n % LANES == 0:
-        r = n // LANES
-        ts = tuple(
-            x.reshape(x.shape[:-1] + (r, LANES)) for x in leaves)
-        ts = log_scan(spec, ts, axis=ts[0].ndim - 1)
-        tot = tuple(t[..., LANES - 1] for t in ts)      # per-row totals
-        off = log_scan(spec, tot, axis=tot[0].ndim - 1)
-        off = shift_one(spec, off, axis=off[0].ndim - 1)  # exclusive
-        ts = spec.combine(tuple(o[..., None] for o in off), ts)
-        return tuple(t.reshape(x.shape) for t, x in zip(ts, leaves))
-    return log_scan(spec, leaves, axis)
-
-
-def _pad_to(x, m, axis, fill):
-    """Pad ``x`` up to length ``m`` along ``axis`` with the identity."""
-    pad = [(0, 0)] * x.ndim
-    pad[axis] = (0, m - x.shape[axis])
-    return jnp.pad(x, pad, constant_values=fill)
-
-
-def tree_scan(spec: KernelSpec, leaves, axis):
-    """In-tile EXCLUSIVE scan in the §3.3 balanced tree's association.
-
-    Blelloch's up-sweep/down-sweep written in place over the whole tile,
-    so the compiler sees only shifts, an iota and selects — no reshape
-    of the lane axis. Level ``s`` (1, 2, 4, …) of the up-sweep combines
-    each right child with its left sibling ``s`` slots earlier
-    (``combine(left, right)`` — left argument earlier, preserving
-    non-commutative order); the down-sweep seeds the root with the
-    identity and walks back down, handing each left child its parent's
-    prefix and each right child ``combine(parent, old_left)``, the
-    convention the ``core.scan.tree`` oracle pins. The tree's
-    association is the recursive pairwise one; the masked passes are
-    full-width, log2(n) of them each way, all inside VMEM — each a
-    combine plus selects over the whole tile, so the sweep does
-    O(n log n) work, not the O(n) of a compacting tree.
-
-    Pads the scan axis to a power of two with the monoid identity (the
-    padded tail contributes identity to every prefix and to the root
-    total, so the slice-back is exact). Returns ``(exclusive_scan,
-    total)`` where the total keeps a size-1 scan axis (the shape
-    ``layout.take_last`` produces, so the inter-block carry chain is
-    shared with the carry schedule verbatim) — the inclusive form is one
-    ``combine(exclusive, elems)`` away, which the tree body fuses into
-    its carry application.
-    """
-    n = leaves[0].shape[axis]
-    m = 1
-    while m < n:
-        m *= 2
-    if m != n:
-        leaves = tuple(
-            _pad_to(x, m, axis, f) for x, f in zip(leaves, spec.fills))
-    pos = jax.lax.broadcasted_iota(jnp.int32, leaves[0].shape, axis)
-
-    def select(mask, new, old):
-        return tuple(jnp.where(mask, a, b) for a, b in zip(new, old))
-
-    def shifted(xs, k):
-        return tuple(_shift(x, k, axis, f) for x, f in zip(xs, spec.fills))
-
-    s = 1
-    while s < m:                                   # up-sweep
-        right = (pos & (2 * s - 1)) == 2 * s - 1
-        leaves = select(right, spec.combine(shifted(leaves, s), leaves),
-                        leaves)
-        s *= 2
-    total = tuple(jax.lax.slice_in_dim(x, m - 1, m, axis=axis)
-                  for x in leaves)
-    root = pos == m - 1
-    xs = select(root, tuple(jnp.full_like(x, f)
-                            for x, f in zip(leaves, spec.fills)), leaves)
-    s = m // 2
-    while s >= 1:                                  # down-sweep
-        phase = pos & (2 * s - 1)
-        parent = shifted(xs, -s)                   # right sibling's slot
-        right = select(phase == 2 * s - 1,
-                       spec.combine(xs, shifted(xs, s)), xs)
-        xs = select(phase == s - 1, parent, right)
-        s //= 2
-    if m != n:
-        xs = tuple(jax.lax.slice_in_dim(x, 0, n, axis=axis) for x in xs)
-    return xs, total
 
 
 def exclusive_chain(spec: KernelSpec, totals, axis: int = 1):
@@ -266,7 +141,7 @@ def exclusive_chain(spec: KernelSpec, totals, axis: int = 1):
 def _scan_block(spec, layout, data_refs, elem_dts):
     raw = tuple(layout.read(r) for r in data_refs)
     elems = tuple(r.astype(dt) for r, dt in zip(raw, elem_dts))
-    scanned = tile_scan(spec, elems, layout.scan_axis)
+    scanned = layout.scan_tile(spec, elems)
     return elems, scanned
 
 
@@ -306,7 +181,7 @@ def _carry_body(*refs, spec, layout, elem_dts, n_out, exclusive, n_tot):
 
     elems, scanned = _scan_block(spec, layout, data_refs, elem_dts)
     carry = tuple(layout.read_carry(r) for r in carry_refs)
-    sel = shift_one(spec, scanned, layout.scan_axis) if exclusive else scanned
+    sel = layout.shift_one(spec, scanned) if exclusive else scanned
     combined = spec.combine(carry, sel)       # carry is the EARLIER operand
     _emit(spec, layout, out_refs, elems, combined)
     new_carry = spec.combine(
@@ -370,7 +245,7 @@ def _apply_body(*refs, spec, layout, elem_dts, n_out, exclusive):
     out_refs = refs[n_ops + n_elem:]
     elems, scanned = _scan_block(spec, layout, data_refs, elem_dts)
     carry = tuple(layout.read_chain(r) for r in off_refs)
-    sel = shift_one(spec, scanned, layout.scan_axis) if exclusive else scanned
+    sel = layout.shift_one(spec, scanned) if exclusive else scanned
     combined = spec.combine(carry, sel)
     _emit(spec, layout, out_refs, elems, combined)
 
@@ -473,7 +348,7 @@ def _fused_body(*refs, spec, layout, elem_dts, n_out, exclusive):
             cp.wait()
         pltpu.semaphore_signal(layout.sem_at(sems, j), 1)
 
-    sel = shift_one(spec, scanned, layout.scan_axis) if exclusive else scanned
+    sel = layout.shift_one(spec, scanned) if exclusive else scanned
     combined = spec.combine(prefix, sel)
     _emit(spec, layout, out_refs, elems, combined)
 
@@ -788,7 +663,8 @@ def fold_decoupled(operands, spec, layout, *, interpret=False):
 
 
 def _launch_event(operands, spec: KernelSpec, layout, schedule: str) -> None:
-    """Record a ``kernel.launch`` trace event: monoid, schedule, grid
+    """Record a ``kernel.launch`` trace event: monoid, schedule, the
+    layout that tiles the operands (``"rows"``, ``"flat"``, ...), grid
     shape, a VMEM working-set estimate (one grid cell's operand blocks),
     and the schedule's slow-memory traffic estimate (read/write bytes —
     the quantity the roofline memory term and ``benchmarks.common
@@ -833,7 +709,7 @@ def _launch_event(operands, spec: KernelSpec, layout, schedule: str) -> None:
         else in_bytes
     trace.instant(
         "kernel.launch", monoid=spec.name, schedule=schedule,
-        fold=is_fold, grid=list(grid),
+        layout=type(layout).__name__.lower(), fold=is_fold, grid=list(grid),
         vmem_block_bytes_est=vmem_est,
         hbm_read_bytes_est=reads, hbm_write_bytes_est=out_bytes)
 

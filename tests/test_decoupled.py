@@ -75,6 +75,110 @@ def test_cumsum_decoupled_exclusive():
 
 
 # ---------------------------------------------------------------------------
+# cumsum of fewer than eight rows: the Flat layout
+# ---------------------------------------------------------------------------
+
+
+def _flat_input(b, n, dtype, seed):
+    rng = np.random.default_rng(seed)
+    if dtype == jnp.int32:
+        return jnp.asarray(rng.integers(-9, 9, (b, n)), dtype)
+    return jnp.asarray(rng.standard_normal((b, n)), dtype)
+
+
+def _running_sum(x, exclusive):
+    inc = np.cumsum(np.asarray(x, np.float64), -1)
+    if not exclusive:
+        return inc
+    return np.concatenate([np.zeros_like(inc[:, :1]), inc[:, :-1]], -1)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
+@pytest.mark.parametrize("exclusive", [False, True])
+@pytest.mark.parametrize("n", [4 * 4096, 100003])   # whole tiles, ragged
+@pytest.mark.parametrize("b", [1, 3])
+def test_flat_cumsum_matches_float64(b, n, exclusive, dtype):
+    """Both schedules the policy picks, on lane-dense (n/128, 128) tiles,
+    against the float64 running sum: exact on integers, and carry and
+    decoupled bitwise equal."""
+    x = _flat_input(b, n, dtype, seed=b * n)
+    ref = _running_sum(x, exclusive)
+    got = {s: sb_ops.cumsum(x, exclusive=exclusive, interpret=True,
+                            schedule=s, block_n=4096)
+           for s in ("carry", "decoupled")}
+    assert jnp.array_equal(got["carry"], got["decoupled"])
+    y = np.asarray(got["decoupled"], np.float64)
+    if dtype == jnp.int32:
+        np.testing.assert_array_equal(y, ref)
+    else:
+        scale = max(1.0, np.abs(ref).max())
+        assert np.abs(y - ref).max() / scale < 1e-4
+
+
+@pytest.mark.parametrize("exclusive", [False, True])
+def test_flat_carry_and_decoupled_bitwise_equal(exclusive):
+    """The totals pass's chunk total is the scan's last element, so the
+    decoupled chain combines exactly the carry schedule's values."""
+    x = _flat_input(1, 6 * 8192 + 77, jnp.float32, seed=11)
+    carry = sb_ops.cumsum(x, exclusive=exclusive, interpret=True,
+                          schedule="carry", block_n=8192)
+    dec = sb_ops.cumsum(x, exclusive=exclusive, interpret=True,
+                        schedule="decoupled", block_n=8192)
+    assert jnp.all(carry == dec), "schedules must agree BITWISE"
+
+
+@pytest.mark.parametrize("exclusive", [False, True])
+@pytest.mark.parametrize("block_n", [None, 1024, 3000, 8192])
+def test_flat_chunk_invariance(block_n, exclusive):
+    """Any chunk length gives the running sum, down to tiles of one vreg:
+    exactly on integers (a block_n off whole vregs is rounded up to
+    them), to rounding on floats."""
+    xi = _flat_input(2, 3 * 8192, jnp.int32, seed=12)
+    got = sb_ops.cumsum(xi, exclusive=exclusive, interpret=True,
+                        schedule="decoupled", block_n=block_n)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  _running_sum(xi, exclusive))
+    xf = _flat_input(2, 3 * 8192, jnp.float32, seed=13)
+    got = sb_ops.cumsum(xf, exclusive=exclusive, interpret=True,
+                        schedule="decoupled", block_n=block_n)
+    np.testing.assert_allclose(np.asarray(got), _running_sum(xf, exclusive),
+                               rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("exclusive", [False, True])
+def test_flat_gradient_is_the_suffix_sum(exclusive):
+    """d/dx of <w, cumsum(x)> for one column is the suffix sum of w (one
+    place later when exclusive), run through the same Flat kernel."""
+    n = 2 * 4096 + 5
+    x = _flat_input(1, n, jnp.float32, seed=14)[0]
+    w = _flat_input(1, n, jnp.float32, seed=15)[0]
+    grad = jax.grad(lambda v: jnp.sum(w * sb_ops.cumsum(
+        v, exclusive=exclusive, interpret=True, block_n=4096)))(x)
+    wh = np.asarray(w, np.float64)
+    ref = np.cumsum(wh[::-1])[::-1]
+    if exclusive:
+        ref = np.concatenate([ref[1:], [0.0]])
+    np.testing.assert_allclose(np.asarray(grad), ref, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("b,layout", [(1, "flat"), (8, "rows")])
+def test_kernel_launch_names_the_layout(b, layout):
+    """A trace says which layout served each launch: one column runs on
+    Flat, eight rows fill a vreg's sublanes and stay on Rows."""
+    from repro.obs import trace
+    x = _flat_input(b, 3 * 1024 + b, jnp.float32, seed=16)
+    t = trace.enable()
+    t.clear()
+    try:
+        sb_ops.cumsum(x, interpret=True, schedule="decoupled")
+        evs = [e["args"] for e in t.events()
+               if e["name"] == "kernel.launch"]
+    finally:
+        trace.disable()
+    assert evs and all(e["layout"] == layout for e in evs)
+
+
+# ---------------------------------------------------------------------------
 # segscan ((flag, value) monoid)
 # ---------------------------------------------------------------------------
 

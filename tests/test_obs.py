@@ -572,14 +572,14 @@ def test_committed_baselines_are_valid():
 
 def test_kernel_launch_event_per_compilation(tracer):
     from repro.kernels.scan_blocked import ops as sb_ops
-    x = jnp.asarray(np.random.default_rng(0).standard_normal((2, 2048)),
+    x = jnp.asarray(np.random.default_rng(0).standard_normal((2, 4096)),
                     jnp.float32)
-    sb_ops.cumsum(x, interpret=True, schedule="decoupled", block_n=512)
+    sb_ops.cumsum(x, interpret=True, schedule="decoupled", block_n=1024)
     evs = [e for e in tracer.events() if e["name"] == "kernel.launch"]
     assert evs, "no kernel.launch event for a fresh scan"
     args = evs[0]["args"]
     assert args["monoid"] == "sum" and args["schedule"] == "decoupled"
-    # Launch grid: row blocks x 4 sequence chunks (2048 / block_n=512).
+    # Launch grid: rows x 4 sequence chunks (4096 / block_n=1024).
     assert args["grid"][-1] == 4 and len(args["grid"]) == 2
     # Decoupled reads the data twice (reduce pass + rescan pass).
     assert args["hbm_read_bytes_est"] == 2 * args["hbm_write_bytes_est"]

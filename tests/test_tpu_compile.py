@@ -13,6 +13,7 @@ described in the module-scoped fixture, which skips when it cannot be.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -127,6 +128,38 @@ def test_public_cumsum_compiles(one_chip, n):
     public policy path."""
     _compile(lambda x: scanlib.cumsum(x, interpret=False), one_chip,
              ((n,), F32))
+
+
+_ARRAY = re.compile(
+    r"^\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(", re.M)
+
+
+def _arrays(hlo_text):
+    """(elements, bytes, opcode) of every array-valued instruction."""
+    out = []
+    for dt, dims, op in _ARRAY.findall(hlo_text):
+        size = 1
+        for d in filter(None, dims.split(",")):
+            size *= int(d)
+        bits = int(re.sub(r"\D", "", dt) or 8)        # pred: a byte
+        out.append((size, size * bits // 8, op))
+    return out
+
+
+def test_public_cumsum_column_reaches_the_kernels_by_bitcast(one_chip):
+    """scan.column's call: a 2^28 f32 column is viewed as lane-dense
+    (n/1024, 8, 128) vregs by bitcasts alone — no copy or relayout of
+    the 1 GiB operand — and its chunk totals are 1,024 (1, 128) planes,
+    every chain array under 1 MiB."""
+    n = 1 << 28
+    compiled = _compile(lambda x: scanlib.cumsum(x, interpret=False),
+                        one_chip, ((n,), F32))
+    arrays = _arrays(compiled.as_text())
+    column = {op for size, _, op in arrays if size == n}
+    assert column == {"parameter", "bitcast", "custom-call"}, column
+    chains = [nbytes for size, nbytes, _ in arrays if 1 < size < n]
+    assert chains and max(chains) < 1 << 20, chains
+    assert max(size for size, _, _ in arrays if size < n) == 1024 * 128
 
 
 @pytest.mark.parametrize("n", [2049, 65536])
